@@ -25,8 +25,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.codegen import (bind_cell_params, cell_stage_runner, compile_spec,
-                           pallas_backend, ssm_params)
+                           ssm_params)
 from repro.core.synthesis import NetworkSpec
+from repro.kernels.platform import resolve_interpret
 from repro.recurrent import cells as rnn_cells
 
 from .common import emit, time_call
@@ -122,6 +123,6 @@ def run(out_dir: str = "experiments") -> list[dict]:
         w.writerows(rows)
     # JSON next to the bench sources — CI uploads benchmarks/*.json artifacts
     with open(os.path.join(os.path.dirname(__file__), "codegen_bench.json"), "w") as f:
-        json.dump({"batch": BATCH, "interpret_mode": pallas_backend.INTERPRET,
+        json.dump({"batch": BATCH, "interpret_mode": resolve_interpret(),
                    "rows": rows}, f, indent=2)
     return rows

@@ -45,6 +45,10 @@ def main() -> None:
     ap.add_argument("--out", default="experiments")
     args = ap.parse_args()
 
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
+
     from . import (codegen_bench, fig3_jstep, fig5_cslow, fig10_generator,
                    fig11_snr, int8_serving, kernels_bench, lstm_throughput,
                    perf_suite, roofline, table1_api, tune_suite)

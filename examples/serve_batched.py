@@ -1,7 +1,10 @@
 """Batched serving demo: slot-based continuous batching over the decode
 state-space step, with per-request latency stats.
 
-    python -m examples.serve_batched --arch falcon-mamba-7b --requests 12
+    python -m examples.serve_batched --arch falcon-mamba-7b --smoke --requests 12
+
+``--smoke`` serves the reduced CPU-sized config; without it the arch's
+full ``CONFIG``.
 """
 
 import argparse
@@ -10,7 +13,7 @@ import time
 import jax
 import numpy as np
 
-from repro.configs import get_smoke_config
+from repro.configs import get_config, get_smoke_config
 from repro.models import lm
 from repro.runtime import DecodeServer, Request
 
@@ -18,6 +21,8 @@ from repro.runtime import DecodeServer, Request
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-135m")
+    ap.add_argument("--smoke", action="store_true",
+                    help="reduced config (CPU); default is the full CONFIG")
     ap.add_argument("--requests", type=int, default=12)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-new", type=int, default=12)
@@ -65,7 +70,7 @@ def main() -> None:
                          "dispatch (single-host C-slow composition)")
     args = ap.parse_args()
 
-    cfg = get_smoke_config(args.arch)
+    cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if args.codegen:
         import dataclasses
 

@@ -4,9 +4,17 @@ Generalizes the hand-written ``kernels/lstm_cell`` pattern to any datapath
 graph: grid ``(B/bb, T/ct)`` with the batch axis parallel and the chunk
 axis sequential; every state register is a VMEM scratch that persists
 across chunks (the paper's eq. 1 state register, never spilled to HBM
-between chunks); within a chunk the ``ct`` steps are a static unroll (the
-j knob); the graph is evaluated per step by the SAME ``ir.eval_graph`` the
-XLA backend uses — macc nodes hit the MXU, gate algebra the VPU.
+between chunks); within a chunk the ``ct`` steps are one loop over
+time-major ``[ct, bb, ·]`` blocks, so the kernel's code size (and its
+compile time) is one step's, not ``ct`` steps'; the graph is evaluated per
+step by the SAME ``ir.eval_graph`` the XLA backend uses — macc nodes hit
+the MXU, gate algebra the VPU.
+
+VMEM: shared weight ROMs are resident and single-buffered (their block
+index never changes), and the kernel asks Mosaic for the scoped VMEM its
+blocks, buffers and scratch actually add up to — a ``[2048, 4096]`` f32
+gate ROM (paper-lstm's D=H=1024) is 32 MiB on its own, twice Mosaic's
+default limit.
 
 Ragged shapes: ``B`` and ``T`` are padded up to the block/chunk multiple and
 the padded tail steps are masked out of the state update, so prime-sized
@@ -49,9 +57,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro import obs as obs_lib
 from repro.core.state_space import ACTIVATIONS
-from repro.kernels._compat import CompilerParams
 from repro.kernels._lut import lut_interpolate, shifted_table
 from repro.kernels.int8_matmul.ops import quantize_per_channel
+from repro.kernels.platform import resolve_interpret, vmem_bytes, vmem_limit
 
 from .ir import DatapathGraph, Program, Stage, eval_graph
 
@@ -59,10 +67,6 @@ PyTree = Any
 
 DEFAULT_CHUNK = 32
 DEFAULT_BLOCK_B = 8
-
-# Tests force interpret mode (CPU container); TPU deployments flip to False —
-# same convention as the hand-written kernels' ops.py.
-INTERPRET = True
 
 
 def _act_resolver(lut_refs, n_lut: int) -> Callable:
@@ -142,7 +146,7 @@ def compile_stage(stage: Stage, *, lut=None, chunk: int = DEFAULT_CHUNK,
     out_width = graph.node(graph.output).width if has_out else 0
     n_state = len(state_names)
     n_lut = 0 if lut is None else int(lut.shape[0])
-    itp = INTERPRET if interpret is None else interpret
+    itp = resolve_interpret(interpret)
     int8 = quant_bits is not None and quant_bits <= 8
     qnames = set(graph.quantizable_weights()) if int8 else set()
     ps_q = [n for n in per_step if n in qnames]       # streamed int8 ROMs
@@ -187,6 +191,8 @@ def compile_stage(stage: Stage, *, lut=None, chunk: int = DEFAULT_CHUNK,
         i += n_state
         scr = {name: refs[i + j] for j, name in enumerate(state_names)}
         i += n_state
+        dq_scr = {name: refs[i + j] for j, name in enumerate(sh_q)}
+        i += len(sh_q)
         if db:
             stream_scr = {name: refs[i + j]
                           for j, name in enumerate(stream_names)}
@@ -199,6 +205,14 @@ def compile_stage(stage: Stage, *, lut=None, chunk: int = DEFAULT_CHUNK,
         def _init():
             for name in state_names:
                 scr[name][...] = s0_refs[name][...].astype(jnp.float32)
+            for name in sh_q:
+                # dequant the WHOLE shared int8 ROM once per batch block
+                # into f32 scratch ((x @ w_q)·s ≡ x @ (w_q·s), per-output-
+                # channel s), so the per-step MACC is the same plain f32
+                # dot as the fp32 path — only per-step DMA'd pages pay a
+                # fused post-dot rescale
+                dq_scr[name][...] = (sh_refs[name][...].astype(jnp.float32)
+                                     * sh_scale[name][...])
 
         def hbm_of(name):
             return ps_scale[name[:-6]] if name.endswith(".scale") else ps_refs[name]
@@ -232,27 +246,17 @@ def compile_stage(stage: Stage, *, lut=None, chunk: int = DEFAULT_CHUNK,
             return stream_scr[name][slot, t] if db else hbm_of(name)[t]
 
         act = _act_resolver(lut_refs, n_lut)
-        shared_vals = {name: sh_refs[name][...] for name in shared_names}
-        for name in sh_q:
-            # hoist the WHOLE dequant out of the step loop: a shared weight
-            # ROM stays int8-resident in VMEM but is cast+rescaled once per
-            # grid cell ((x @ w_q)·s ≡ x @ (w_q·s), per-output-channel s),
-            # so the per-step MACC is the same plain f32 dot as the fp32
-            # path — only per-step DMA'd pages pay a fused post-dot rescale
-            shared_vals[name] = shared_vals[name].astype(jnp.float32) \
-                * sh_scale[name][...]
-        states = {name: scr[name][...] for name in state_names}
+        ragged = num_chunks * ct != t_total
 
-        ys = []
-        for t in range(ct):  # static unroll within the chunk — the j knob
-            u_t = x_ref[:, t, :].astype(jnp.float32) if inp is not None else None
+        def step(t, states):
+            u_t = x_ref[t].astype(jnp.float32) if inp is not None else None
 
-            def consts_get(name, t=t):
+            def consts_get(name):
                 if name in per_step:
                     return page(name, t)
-                return shared_vals[name]
+                return (dq_scr if name in dq_scr else sh_refs)[name][...]
 
-            def mm(x, w_name, w, t=t):
+            def mm(x, w_name, w):
                 if w_name not in ps_q:
                     return x @ w    # fp32, or shared int8 dequanted above
                 # weight-only int8 page: f32 activations × int8 codes,
@@ -265,19 +269,19 @@ def compile_stage(stage: Stage, *, lut=None, chunk: int = DEFAULT_CHUNK,
 
             new_states, y = eval_graph(graph, consts=consts_get, states=states,
                                        u=u_t, act=act, mm=mm)
-            if num_chunks * ct != t_total:
+            if ragged:
                 # ragged T: padded tail steps must not advance the registers
                 valid = ci * ct + t < t_total
                 new_states = {k: jnp.where(valid, new_states[k], states[k])
                               for k in new_states}
-            states = new_states
             if has_out:
-                ys.append(y)
+                y_ref[t] = y.astype(y_ref.dtype)
+            return new_states
 
+        states = jax.lax.fori_loop(
+            0, ct, step, {name: scr[name][...] for name in state_names})
         for name in state_names:
             scr[name][...] = states[name]
-        if has_out:
-            y_ref[...] = jnp.stack(ys, axis=1).astype(y_ref.dtype)
 
         @pl.when(ci == num_chunks - 1)
         def _fin():
@@ -295,22 +299,40 @@ def compile_stage(stage: Stage, *, lut=None, chunk: int = DEFAULT_CHUNK,
         num_chunks = Tp // ct
         db = double_buffer and bool(per_step)
 
+        vmem = 0    # bytes of every VMEM buffer below → the scoped limit
+
+        def block(shape, index_map, dtype=jnp.float32, *, invariant=False):
+            """A VMEM block; grid-invariant blocks (weight ROMs, LUTs) are
+            single-buffered — fetched once, never re-streamed."""
+            nonlocal vmem
+            vmem += vmem_bytes(shape, dtype) * (1 if invariant else 2)
+            if invariant:
+                return pl.BlockSpec(shape, index_map,
+                                    pipeline_mode=pl.Buffered(1))
+            return pl.BlockSpec(shape, index_map)
+
+        def scratch(shape, dtype=jnp.float32):
+            nonlocal vmem
+            vmem += vmem_bytes(shape, dtype)
+            return pltpu.VMEM(shape, dtype)
+
         in_specs, operands = [], []
         if inp is not None:
             D = inp.width
-            in_specs.append(pl.BlockSpec((bb, ct, D), lambda i, c: (i, c, 0)))
-            operands.append(_pad_to(_pad_to(
-                jnp.asarray(us, jnp.float32), Bp, 0), Tp, 1))
+            in_specs.append(block((ct, bb, D), lambda i, c: (c, i, 0)))
+            us_tm = jnp.swapaxes(jnp.asarray(us, jnp.float32), 0, 1)
+            operands.append(_pad_to(_pad_to(us_tm, Tp, 0), Bp, 1))
 
         def add_stream(arr):
             """Per-step operand: resident in ANY/HBM when double-buffered
             (the kernel DMAs chunk slices itself), BlockSpec-chunked else."""
             if db:
-                in_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))
+                in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
             else:
                 tail = arr.shape[1:]
-                in_specs.append(pl.BlockSpec(
-                    (ct,) + tail, lambda i, c, nd=len(tail): (c,) + (0,) * nd))
+                in_specs.append(block(
+                    (ct,) + tail, lambda i, c, nd=len(tail): (c,) + (0,) * nd,
+                    arr.dtype))
             operands.append(arr)
 
         def packed(name):
@@ -333,48 +355,52 @@ def compile_stage(stage: Stage, *, lut=None, chunk: int = DEFAULT_CHUNK,
             add_stream(_pad_to(arr, Tp, 0))
         for name in ps_q:
             add_stream(_pad_to(ps_scales[name], Tp, 0))
-        sh_scales = {}
+        sh_scales, sh_shapes = {}, {}
         for name in shared_names:
             if name in qnames:
                 arr, sh_scales[name] = packed(name)
             else:
                 arr = jnp.asarray(consts[name], jnp.float32)
-            in_specs.append(pl.BlockSpec(
-                arr.shape, lambda i, c, nd=arr.ndim: (0,) * nd))
+            sh_shapes[name] = arr.shape
+            in_specs.append(block(arr.shape,
+                                  lambda i, c, nd=arr.ndim: (0,) * nd,
+                                  arr.dtype, invariant=True))
             operands.append(arr)
         for name in sh_q:
             arr = sh_scales[name]
-            in_specs.append(pl.BlockSpec(
-                arr.shape, lambda i, c, nd=arr.ndim: (0,) * nd))
+            in_specs.append(block(arr.shape,
+                                  lambda i, c, nd=arr.ndim: (0,) * nd,
+                                  invariant=True))
             operands.append(arr)
         for name in state_names:
             w = graph.states[name]
-            in_specs.append(pl.BlockSpec((bb, w), lambda i, c: (i, 0)))
+            in_specs.append(block((bb, w), lambda i, c: (i, 0)))
             operands.append(_pad_to(jnp.asarray(x0[name], jnp.float32), Bp, 0))
         if n_lut:
             lut1 = shifted_table(lut)
-            in_specs += [pl.BlockSpec((1, n_lut), lambda i, c: (0, 0))] * 2
+            in_specs += [block((1, n_lut), lambda i, c: (0, 0), invariant=True)
+                         for _ in range(2)]
             operands += [jnp.asarray(lut, jnp.float32)[None],
                          jnp.asarray(lut1, jnp.float32)[None]]
 
         out_specs, out_shape = [], []
         if has_out:
-            out_specs.append(pl.BlockSpec((bb, ct, out_width),
-                                          lambda i, c: (i, c, 0)))
-            out_shape.append(jax.ShapeDtypeStruct((Bp, Tp, out_width), jnp.float32))
+            out_specs.append(block((ct, bb, out_width), lambda i, c: (c, i, 0)))
+            out_shape.append(jax.ShapeDtypeStruct((Tp, Bp, out_width), jnp.float32))
         for name in state_names:
             w = graph.states[name]
-            out_specs.append(pl.BlockSpec((bb, w), lambda i, c: (i, 0)))
+            out_specs.append(block((bb, w), lambda i, c: (i, 0)))
             out_shape.append(jax.ShapeDtypeStruct((Bp, w), jnp.float32))
 
-        scratch_shapes = [pltpu.VMEM((bb, graph.states[n]), jnp.float32)
-                          for n in state_names]
+        scratch_shapes = [scratch((bb, graph.states[n])) for n in state_names]
+        # f32 dequant of each shared int8 ROM, filled once per batch block
+        scratch_shapes += [scratch(sh_shapes[n]) for n in sh_q]
         if db:
             # the 2-slot prefetch buffers + one DMA semaphore per (stream, slot)
             for j, name in enumerate(stream_names):
                 src = operands[(1 if inp is not None else 0) + j]
                 scratch_shapes.append(
-                    pltpu.VMEM((2, ct) + src.shape[1:], src.dtype))
+                    scratch((2, ct) + src.shape[1:], src.dtype))
             scratch_shapes.append(pltpu.SemaphoreType.DMA((len(stream_names), 2)))
 
         results = pl.pallas_call(
@@ -384,8 +410,9 @@ def compile_stage(stage: Stage, *, lut=None, chunk: int = DEFAULT_CHUNK,
             out_specs=out_specs,
             out_shape=out_shape,
             scratch_shapes=scratch_shapes,
-            compiler_params=CompilerParams(
-                dimension_semantics=("parallel", "arbitrary")
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary"),
+                vmem_limit_bytes=vmem_limit(vmem),
             ),
             interpret=itp,
         )(*operands)
@@ -393,7 +420,7 @@ def compile_stage(stage: Stage, *, lut=None, chunk: int = DEFAULT_CHUNK,
         o = 0
         ys = None
         if has_out:
-            ys, o = results[0][:B, :T], 1
+            ys, o = jnp.swapaxes(results[0][:T, :B], 0, 1), 1
         finals = {name: results[o + j][:B] for j, name in enumerate(state_names)}
         return finals, ys
 
@@ -421,7 +448,8 @@ def compile_program(program: Program, *, lut=None,
     each shard folds its LOCAL streams into its own kernel grid —
     ``c_slow × data_shards`` compose on the same batch dimension (ROM
     double-buffering stays per-device; params replicate).  A leading axis
-    that doesn't divide the DP size falls back to the single-device path.
+    that doesn't divide the DP size is zero-padded up to it (the padded
+    streams are independent and sliced off), so every data shard runs.
     """
     from repro.core.cslow import fold_streams, unfold_streams
 
@@ -462,8 +490,6 @@ def compile_program(program: Program, *, lut=None,
         return forward
     from jax.sharding import PartitionSpec as P
 
-    from repro.parallel._compat import shard_map
-
     dp = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
     dp_n = 1
     for a in dp:
@@ -471,12 +497,12 @@ def compile_program(program: Program, *, lut=None,
     if dp_n <= 1:
         return forward
 
+    sm = jax.shard_map(forward, mesh=mesh, in_specs=(P(), P(dp)),
+                       out_specs=P(dp), check_vma=False)
+
     def sharded_forward(params: PyTree, u: jnp.ndarray) -> jnp.ndarray:
         u = jnp.asarray(u, jnp.float32)
-        if u.shape[0] % dp_n:
-            return forward(params, u)      # ragged leading axis: one device
-        sm = shard_map(forward, mesh=mesh, in_specs=(P(), P(dp)),
-                       out_specs=P(dp), check_rep=False)
-        return sm(params, u)
+        n = u.shape[0]
+        return sm(params, _pad_to(u, -(-n // dp_n) * dp_n, 0))[:n]
 
     return sharded_forward

@@ -372,7 +372,8 @@ def _measure_compiled(compiled, params, u_shape, key: str) -> None:
         pass
 
 
-# Degradation order when a backend's compile step keeps failing: the fused
+# Degradation order when a backend's compile step keeps failing with a
+# TransientFault (any other error is re-raised): the fused
 # pallas kernel falls back to the plain XLA scan, and that falls back to the
 # unlowered reference forward ("ref": create_top_module + vmap — no codegen
 # IR in the compile path at all).  verilog's *compiled* artifact is the XLA
@@ -511,12 +512,15 @@ def synthesize(spec: NetworkSpec, batch: int | None = None,
     and ``cost_analysis`` flops against measured wall-clock
     (``measure=False`` skips the timed execution).
 
-    Robustness: a transient compile failure (an injected ``synth.compile``
-    fault, or a flaky backend) is retried up to ``retries`` times with
-    exponential ``backoff_s`` backoff; a backend that keeps failing degrades
-    down the pallas → xla → ref chain (``fallback=False`` re-raises
-    instead).  The returned report's ``backend`` is the backend that
-    actually compiled; ``fallback_from`` records the requested one, and the
+    Robustness: a transient compile failure (a ``TransientFault``, e.g. an
+    injected ``synth.compile`` fault) is retried up to ``retries`` times
+    with exponential ``backoff_s`` backoff; a backend that keeps failing
+    transiently degrades down the pallas → xla → ref chain
+    (``fallback=False`` re-raises instead).  Any other error — a kernel the
+    device's compiler refuses, a shape bug — is re-raised at once, so a
+    report never hides which backend really ran.  The returned report's
+    ``backend`` is the backend that actually compiled; ``fallback_from``
+    records the requested one, and the
     ``synth_retries`` / ``synth_fallback{from_backend,to}`` counters track
     both events.
 
@@ -587,15 +591,17 @@ def synthesize(spec: NetworkSpec, batch: int | None = None,
                                           mesh)
                 analysis = _analyze_compiled(fwd, bparams, u)
                 break
-            except Exception as e:  # noqa: BLE001 — degrade, don't die
+            except Exception as e:  # noqa: BLE001 — only transients degrade
+                if not _is_transient(e):
+                    raise   # a real compile error must not hide behind a fallback
                 last_err = e
-                if _is_transient(e) and attempt < retries:
+                if attempt < retries:
                     O.metrics.counter("synth_retries",
                                       "transient compile retries").inc()
                     if backoff_s > 0:
                         time.sleep(backoff_s * (2 ** attempt))
                     continue
-                break   # non-transient, or retries exhausted: next backend
+                break   # retries exhausted: next backend
         if analysis is not None:
             used = bk
             break

@@ -5,6 +5,7 @@
   int8_matmul     — fixed-point MACC matmul (DSP48E1 → MXU int8 path)
   tanh_lut        — ROM-LUT activation via one-hot MXU gather (§IV-B)
 
-All kernels ship ops.py (jit wrapper, INTERPRET switch) and ref.py (oracle);
-tests sweep shapes/dtypes in interpret mode against the oracle.
+All kernels ship ops.py (jit wrapper) and ref.py (oracle).  ``platform``
+picks interpret mode from the backend (interpreter off-TPU, Mosaic on a
+TPU); tests sweep shapes/dtypes in interpret mode against the oracle.
 """

@@ -1,19 +1,10 @@
-"""Version compatibility for jax APIs the kernels and analysis code touch.
+"""Normalization of ``compiled.cost_analysis()``.
 
-``pltpu.TPUCompilerParams`` was renamed to ``pltpu.CompilerParams`` across
-jax releases; resolve whichever this environment provides so the kernels
-import on both sides of the rename.
-
-``compiled.cost_analysis()`` returns one dict on current jax but a
-list/tuple of per-device dicts on older releases (0.4.x);
-:func:`first_cost_analysis` is the one shared normalization.
+:func:`first_cost_analysis` is the one place that turns whatever the
+compiled object reports into a plain dict (``{}`` when nothing).
 """
 
 from __future__ import annotations
-
-from jax.experimental.pallas import tpu as pltpu
-
-CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 
 def first_cost_analysis(compiled) -> dict:
@@ -26,4 +17,4 @@ def first_cost_analysis(compiled) -> dict:
     return dict(cost) if cost else {}
 
 
-__all__ = ["CompilerParams", "first_cost_analysis"]
+__all__ = ["first_cost_analysis"]

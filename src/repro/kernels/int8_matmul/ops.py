@@ -4,14 +4,14 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from repro.kernels.platform import resolve_interpret
+
 from . import kernel as _k
 from .ref import int8_matmul_ref, quantize_matmul_ref
 
-INTERPRET = True  # CPU container; flip on TPU
-
 
 def int8_matmul(a_q, b_q, a_scale, b_scale, *, interpret=None, **kw):
-    itp = INTERPRET if interpret is None else interpret
+    itp = resolve_interpret(interpret)
     return _k.int8_matmul(a_q, b_q, a_scale, b_scale, interpret=itp, **kw)
 
 
@@ -47,5 +47,4 @@ def quantized_matmul(a, b, *, interpret=None, **kw):
 
 
 __all__ = ["int8_matmul", "quantized_matmul", "int8_matmul_ref",
-           "quantize_matmul_ref", "quantize_per_channel", "quantize_rows",
-           "INTERPRET"]
+           "quantize_matmul_ref", "quantize_per_channel", "quantize_rows"]

@@ -8,9 +8,15 @@ in-VMEM on the VPU, and the ``(h, c)`` carry lives in VMEM scratch that
 persists across the sequential chunk axis — the state register of the
 paper's eq. 1 datapath, never spilled to HBM between chunks.
 
-Grid: (Bsz/bb, T/ct); batch parallel, chunk axis "arbitrary" (sequential)
-so the carry scratch is live across chunks.  VMEM per step: x tile
-[bb·ct·D], weights [(D+H)·4H], carry 2·[bb·H] — ~1 MB at the defaults.
+Grid: (Bp/bb, Tp/ct); batch parallel, chunk axis "arbitrary" (sequential)
+so the carry scratch is live across chunks.  Ragged ``Bsz``/``T`` are
+zero-padded up to the block/chunk multiple and the padded tail steps are
+masked out of the carry update, so a prime prompt length keeps the full
+``ct``-step tile (Mosaic refuses blocks whose last two dims are neither
+multiples of (8, 128) nor the full array).  Within a chunk the steps are a
+loop over time-major ``[ct, bb, ·]`` blocks.  The ``[D+H, 4H]`` weight is
+resident and single-buffered; the kernel asks for the scoped VMEM its
+blocks add up to (32 MiB of f32 weight at D=H=1024).
 
 Quantized path (paper §IV-B): ``lut`` switches the gate activations to the
 ROM-LUT idiom of ``kernels.tanh_lut`` — one-hot × table MXU contractions
@@ -26,8 +32,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
 from repro.kernels._lut import lut_interpolate, shifted_table
+from repro.kernels.platform import vmem_bytes, vmem_limit
 
 DEFAULT_CHUNK = 32
 DEFAULT_BLOCK_B = 8
@@ -45,7 +51,7 @@ def _make_acts(lut_refs, n_lut: int):
 
 
 def _lstm_kernel(x_ref, W_ref, b_ref, h0_ref, c0_ref, *rest,
-                 ct: int, H: int, last_chunk: int, n_lut: int):
+                 ct: int, H: int, last_chunk: int, t_total: int, n_lut: int):
     lut_refs, (y_ref, hout_ref, cout_ref), (h_scr, c_scr) = (
         rest[: 2 if n_lut else 0], rest[-5:-2], rest[-2:]
     )
@@ -57,23 +63,27 @@ def _lstm_kernel(x_ref, W_ref, b_ref, h0_ref, c0_ref, *rest,
         c_scr[...] = c0_ref[...].astype(jnp.float32)
 
     tanh, sig = _make_acts(lut_refs, n_lut)
-    W = W_ref[...].astype(jnp.float32)       # [D+H, 4H]
-    b = b_ref[...].astype(jnp.float32)       # [1, 4H]
-    h, c = h_scr[...], c_scr[...]            # [bb, H] f32
+    ragged = (last_chunk + 1) * ct != t_total
 
-    ys = []
-    for t in range(ct):                      # static unroll within the chunk
-        xt = x_ref[:, t, :].astype(jnp.float32)           # [bb, D]
-        z = jnp.concatenate([xt, h], axis=-1) @ W + b     # ONE contraction
+    def step(t, carry):
+        h, c = carry                                      # [bb, H] f32
+        xt = x_ref[t].astype(jnp.float32)                 # [bb, D]
+        W = W_ref[...].astype(jnp.float32)                # [D+H, 4H]
+        z = jnp.concatenate([xt, h], axis=-1) @ W + b_ref[...].astype(jnp.float32)
         i_g = sig(z[:, :H])
         f_g = sig(z[:, H : 2 * H])
         g_g = tanh(z[:, 2 * H : 3 * H])
         o_g = sig(z[:, 3 * H :])
-        c = f_g * c + i_g * g_g
-        h = o_g * tanh(c)
-        ys.append(h)
+        c_new = f_g * c + i_g * g_g
+        h_new = o_g * tanh(c_new)
+        y_ref[t] = h_new.astype(y_ref.dtype)
+        if ragged:    # padded tail steps must not advance the carry
+            valid = ci * ct + t < t_total
+            h_new = jnp.where(valid, h_new, h)
+            c_new = jnp.where(valid, c_new, c)
+        return h_new, c_new
 
-    y_ref[...] = jnp.stack(ys, axis=1).astype(y_ref.dtype)
+    h, c = jax.lax.fori_loop(0, ct, step, (h_scr[...], c_scr[...]))
     h_scr[...] = h
     c_scr[...] = c
 
@@ -90,57 +100,64 @@ def lstm_seq(x, w_x, w_h, b, h0, c0, lut=None, *, chunk: int = DEFAULT_CHUNK,
     Bsz, T, D = x.shape
     H = w_h.shape[0]
     ct = min(chunk, T)
-    while T % ct:
-        ct //= 2
     bb = min(block_b, Bsz)
-    while Bsz % bb:
-        bb //= 2
+    Tp = -(-T // ct) * ct
+    Bp = -(-Bsz // bb) * bb
 
     W = jnp.concatenate([w_x, w_h], axis=0)  # [D+H, 4H]
     n_lut = 0 if lut is None else lut.shape[0]
+    x_tm = jnp.pad(jnp.swapaxes(x, 0, 1), ((0, Tp - T), (0, Bp - Bsz), (0, 0)))
+    pad_b = lambda a: jnp.pad(a, ((0, Bp - Bsz), (0, 0)))
 
-    grid = (Bsz // bb, T // ct)
+    grid = (Bp // bb, Tp // ct)
     kernel = functools.partial(
-        _lstm_kernel, ct=ct, H=H, last_chunk=T // ct - 1, n_lut=n_lut
-    )
+        _lstm_kernel, ct=ct, H=H, last_chunk=Tp // ct - 1, t_total=T,
+        n_lut=n_lut)
 
+    once = dict(pipeline_mode=pl.Buffered(1))   # grid-invariant: one buffer
     in_specs = [
-        pl.BlockSpec((bb, ct, D), lambda i, c: (i, c, 0)),        # x
-        pl.BlockSpec((D + H, 4 * H), lambda i, c: (0, 0)),        # W
-        pl.BlockSpec((1, 4 * H), lambda i, c: (0, 0)),            # b
+        pl.BlockSpec((ct, bb, D), lambda i, c: (c, i, 0)),        # x
+        pl.BlockSpec((D + H, 4 * H), lambda i, c: (0, 0), **once),  # W
+        pl.BlockSpec((1, 4 * H), lambda i, c: (0, 0), **once),    # b
         pl.BlockSpec((bb, H), lambda i, c: (i, 0)),               # h0
         pl.BlockSpec((bb, H), lambda i, c: (i, 0)),               # c0
     ]
-    operands = [x, W, b[None], h0, c0]
+    operands = [x_tm, W, b[None], pad_b(h0), pad_b(c0)]
+    vmem = (vmem_bytes(W.shape, W.dtype) + vmem_bytes((1, 4 * H), b.dtype)
+            + 2 * vmem_bytes((ct, bb, D), x.dtype)        # x, double-buffered
+            + 2 * vmem_bytes((ct, bb, H), x.dtype)        # y, double-buffered
+            + 10 * vmem_bytes((bb, H), jnp.float32))      # h0 c0 h c outs, scratch
     if n_lut:
         lut1 = shifted_table(lut)
         in_specs += [
-            pl.BlockSpec((1, n_lut), lambda i, c: (0, 0)),        # lut
-            pl.BlockSpec((1, n_lut), lambda i, c: (0, 0)),        # lut shifted
+            pl.BlockSpec((1, n_lut), lambda i, c: (0, 0), **once),  # lut
+            pl.BlockSpec((1, n_lut), lambda i, c: (0, 0), **once),  # shifted
         ]
         operands += [lut[None].astype(jnp.float32), lut1[None].astype(jnp.float32)]
+        vmem += 2 * vmem_bytes((1, n_lut), jnp.float32)
 
     y, h_final, c_final = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((bb, ct, H), lambda i, c: (i, c, 0)),    # y
+            pl.BlockSpec((ct, bb, H), lambda i, c: (c, i, 0)),    # y
             pl.BlockSpec((bb, H), lambda i, c: (i, 0)),           # h_final
             pl.BlockSpec((bb, H), lambda i, c: (i, 0)),           # c_final
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((Bsz, T, H), x.dtype),
-            jax.ShapeDtypeStruct((Bsz, H), jnp.float32),
-            jax.ShapeDtypeStruct((Bsz, H), jnp.float32),
+            jax.ShapeDtypeStruct((Tp, Bp, H), x.dtype),
+            jax.ShapeDtypeStruct((Bp, H), jnp.float32),
+            jax.ShapeDtypeStruct((Bp, H), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bb, H), jnp.float32),
             pltpu.VMEM((bb, H), jnp.float32),
         ],
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit(vmem),
         ),
         interpret=interpret,
     )(*operands)
-    return y, h_final, c_final
+    return jnp.swapaxes(y[:T, :Bsz], 0, 1), h_final[:Bsz], c_final[:Bsz]

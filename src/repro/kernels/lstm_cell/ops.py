@@ -4,12 +4,10 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from repro.kernels.platform import resolve_interpret
+
 from . import kernel as _k
 from .ref import lstm_seq_lut_ref, lstm_seq_ref
-
-# Global switch: tests force interpret mode (CPU); TPU deployments leave it
-# False.  The jnp oracle is always available as lstm_seq_ref.
-INTERPRET = True  # this container is CPU-only; flip on TPU
 
 
 def lstm_seq(x, w_x, w_h, b, h0=None, c0=None, lut=None, *,
@@ -28,9 +26,9 @@ def lstm_seq(x, w_x, w_h, b, h0=None, c0=None, lut=None, *,
         h0 = jnp.zeros((Bsz, H), jnp.float32)
     if c0 is None:
         c0 = jnp.zeros((Bsz, H), jnp.float32)
-    itp = INTERPRET if interpret is None else interpret
+    itp = resolve_interpret(interpret)
     return _k.lstm_seq(x, w_x, w_h, b, h0, c0, lut, chunk=chunk,
                        block_b=block_b, interpret=itp)
 
 
-__all__ = ["lstm_seq", "lstm_seq_ref", "lstm_seq_lut_ref", "INTERPRET"]
+__all__ = ["lstm_seq", "lstm_seq_ref", "lstm_seq_lut_ref"]

@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
 
 
 DEFAULT_CHUNK = 128
@@ -118,7 +117,7 @@ def ssm_scan(x, delta, A, B, C, h0, *, chunk: int = DEFAULT_CHUNK,
             jax.ShapeDtypeStruct((Bsz, D, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((bd, N), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

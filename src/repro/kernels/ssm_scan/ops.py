@@ -4,12 +4,10 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from repro.kernels.platform import resolve_interpret
+
 from . import kernel as _k
 from .ref import ssm_scan_ref
-
-# Global switch: tests force interpret mode (CPU); TPU deployments leave it
-# False.  The jnp oracle is always available as ssm_scan_ref.
-INTERPRET = True  # this container is CPU-only; flip on TPU
 
 
 def _maybe_nonzero(h0) -> bool:
@@ -35,9 +33,9 @@ def ssm_scan(x, delta, A, B, C, h0=None, *, chunk: int = _k.DEFAULT_CHUNK,
         return ssm_scan_ref(x, delta, A, B, C, h0)
     if h0 is None:
         h0 = jnp.zeros((x.shape[0], x.shape[2], B.shape[-1]), jnp.float32)
-    itp = INTERPRET if interpret is None else interpret
+    itp = resolve_interpret(interpret)
     return _k.ssm_scan(x, delta, A, B, C, h0, chunk=chunk, block_d=block_d,
                        w=w, interpret=itp)
 
 
-__all__ = ["ssm_scan", "ssm_scan_ref", "INTERPRET"]
+__all__ = ["ssm_scan", "ssm_scan_ref"]

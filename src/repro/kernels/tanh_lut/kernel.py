@@ -15,8 +15,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
 from repro.kernels._lut import lut_interpolate, shifted_table
 
 DEFAULT_BLOCK = 1024
@@ -50,7 +50,7 @@ def tanh_lut(x, lut, *, block: int = DEFAULT_BLOCK, interpret: bool = False):
         ],
         out_specs=pl.BlockSpec((1, bs), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, S), x.dtype),
-        compiler_params=CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
     )(flat, lut[None], lut1[None])
     return out.reshape(shape)

@@ -2,15 +2,15 @@
 
 from __future__ import annotations
 
+from repro.kernels.platform import resolve_interpret
+
 from . import kernel as _k
 from .ref import make_lut, tanh_lut_ref
 
-INTERPRET = True  # CPU container; flip on TPU
-
 
 def tanh_lut(x, lut, *, block=_k.DEFAULT_BLOCK, interpret=None):
-    itp = INTERPRET if interpret is None else interpret
+    itp = resolve_interpret(interpret)
     return _k.tanh_lut(x, lut, block=block, interpret=itp)
 
 
-__all__ = ["tanh_lut", "tanh_lut_ref", "make_lut", "INTERPRET"]
+__all__ = ["tanh_lut", "tanh_lut_ref", "make_lut"]

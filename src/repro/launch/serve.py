@@ -3,9 +3,12 @@ request stream (see examples/serve_batched.py for the walkthrough).
 
     python -m repro.launch.serve --arch falcon-mamba-7b --smoke --requests 16
 
+``--smoke`` serves the reduced CPU-sized config; without it the arch's
+full ``CONFIG`` is served.
+
 Observability (README §Observability):
 
-    python -m repro.launch.serve --trace-out trace.json --metrics-out metrics.json
+    python -m repro.launch.serve --smoke --trace-out trace.json --metrics-out metrics.json
 
 ``--trace-out`` enables span tracing and writes a Chrome-trace-event JSON
 loadable in Perfetto (https://ui.perfetto.dev); ``--metrics-out`` writes the
@@ -15,7 +18,7 @@ with ``python -m repro.obs.check``.
 Sharded serving (README §Sharded serving) — requires a device pool, e.g.
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` on CPU:
 
-    python -m repro.launch.serve --mesh 8x1 --slots 8 \\
+    python -m repro.launch.serve --smoke --mesh 8x1 --slots 8 \\
         --loadgen --loadgen-out loadgen.json
 
 ``--mesh DPxTP`` maps the slot pool onto a device mesh (``--mesh-layout
@@ -34,7 +37,8 @@ import argparse
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-135m")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action="store_true",
+                    help="reduced config (CPU); default is the full CONFIG")
     ap.add_argument("--requests", type=int, default=16)
     ap.add_argument("--slots", type=int, default=8)
     ap.add_argument("--max-new", type=int, default=16)
@@ -84,6 +88,10 @@ def main() -> None:
                     help="write the repro.loadgen/v1 replay report JSON")
     args = ap.parse_args()
 
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
+
     import json
     import time
 
@@ -91,7 +99,7 @@ def main() -> None:
     import numpy as np
 
     from repro import obs as obs_lib
-    from repro.configs import get_smoke_config
+    from repro.configs import get_config, get_smoke_config
     from repro.models import lm
     from repro.obs import log
     from repro.runtime import (DecodeServer, Request, SchedulerConfig,
@@ -110,7 +118,7 @@ def main() -> None:
         log.info(f"mesh: {plan.describe()}")
 
     obs = obs_lib.Observability(trace=bool(args.trace_out))
-    cfg = get_smoke_config(args.arch)
+    cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     params = lm.init_params(cfg, jax.random.PRNGKey(0))
     server = DecodeServer(cfg, params, num_slots=args.slots, max_seq=args.max_seq,
                           block_k=args.block_k, persistent=args.persistent,

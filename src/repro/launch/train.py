@@ -30,6 +30,10 @@ def main() -> None:
     ap.add_argument("--metrics-out", default=None)
     args = ap.parse_args()
 
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
+
     from repro import optim
     from repro.configs import get_config, get_smoke_config
     from repro.data import DataConfig

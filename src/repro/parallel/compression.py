@@ -17,9 +17,8 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from repro.parallel._compat import shard_map
 
 PyTree = Any
 

@@ -19,9 +19,9 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
+from jax.lax import pcast
 from jax.sharding import Mesh, PartitionSpec as P
-
-from repro.parallel._compat import pcast, shard_map
 
 PyTree = Any
 

@@ -85,6 +85,13 @@ def _codegen_seq(cell: str, p_cell: PyTree, u: jnp.ndarray, carry0,
     if carry0 is None:
         carry0 = cells.init_carry(cell, p_cell, (B,))
     x0 = {"h": carry0[0], "c": carry0[1]} if cell == "lstm" else {"h": carry0}
+    if jax.sharding.get_abstract_mesh().size > 1:
+        # GSPMD cannot partition a Mosaic kernel: under a device mesh (a
+        # sharded DecodeServer traces its prefill inside ``jax.set_mesh``)
+        # every device runs the whole stage on replicated operands
+        from jax.sharding import PartitionSpec as P
+
+        run = jax.shard_map(run, in_specs=P(), out_specs=P(), check_vma=False)
     finals, ys = run(codegen.bind_cell_params(cell, p_cell), x0,
                      u.astype(jnp.float32))
     carry = (finals["h"], finals["c"]) if cell == "lstm" else finals["h"]

@@ -260,7 +260,8 @@ class DecodeServer:
         self._decode = jax.jit(
             lambda p, t, c, pos: lm.decode_step(p, cfg, t, c, pos)
         )
-        self._prefill = jax.jit(lambda p, t: lm.prefill(p, cfg, t))
+        self._prefill = self._on_mesh(
+            jax.jit(lambda p, t: lm.prefill(p, cfg, t)))
         self._chunk_fns: dict[int, Callable] = {}       # chunk len -> jitted
         self._block_fns: dict[int, Callable] = {}       # K -> jitted K-step loop
         self._jobs: list[_PrefillJob] = []
@@ -692,10 +693,25 @@ class DecodeServer:
         fn = self._chunk_fns.get(c)
         if fn is None:
             cfg = self.cfg
-            fn = self._chunk_fns[c] = jax.jit(
+            fn = self._chunk_fns[c] = self._on_mesh(jax.jit(
                 lambda p, t, cc, pos: lm.prefill_chunk(p, cfg, t, cc, pos)
-            )
+            ))
         return fn
+
+    def _on_mesh(self, fn: Callable) -> Callable:
+        """Trace and run a prefill program with the plan's mesh in context
+        when the state is physically sharded: Mosaic kernels inside it (the
+        generated stage) cannot be partitioned by GSPMD, and replicate
+        themselves over the context mesh instead."""
+        if self._repl is None:
+            return fn
+        mesh = self.plan.mesh
+
+        def call(*args):
+            with jax.set_mesh(mesh):
+                return fn(*args)
+
+        return call
 
     def _cache_boundary(self, job: _PrefillJob) -> None:
         """Checkpoint the job's current state into the prefix cache.  Only

@@ -198,30 +198,30 @@ module Create_Layer_layer0_zx #(parameter WIDTH = 16, parameter J = 1) (
   reg signed [WIDTH-1:0] rom [0:23];
   reg signed [WIDTH-1:0] rom_b [0:11];  // bias ROM, one word per lane
   initial begin
-    rom[0] = 16'hf8b9;
-    rom[1] = 16'h0344;
-    rom[2] = 16'h0c80;
-    rom[3] = 16'hfc77;
-    rom[4] = 16'h0573;
-    rom[5] = 16'hfac6;
-    rom[6] = 16'h073d;
-    rom[7] = 16'h0013;
-    rom[8] = 16'h06ee;
-    rom[9] = 16'h0961;
-    rom[10] = 16'h0039;
-    rom[11] = 16'h005d;
-    rom[12] = 16'h04e8;
-    rom[13] = 16'h0853;
-    rom[14] = 16'h0107;
-    rom[15] = 16'h01d2;
-    rom[16] = 16'h062c;
-    rom[17] = 16'h01df;
-    rom[18] = 16'h05ec;
-    rom[19] = 16'hf884;
-    rom[20] = 16'h10fc;
-    rom[21] = 16'h0fec;
-    rom[22] = 16'hffd1;
-    rom[23] = 16'hd9b0;
+    rom[0] = 16'h039c;
+    rom[1] = 16'hf21e;
+    rom[2] = 16'hfc96;
+    rom[3] = 16'hf14a;
+    rom[4] = 16'hf5ac;
+    rom[5] = 16'hfc28;
+    rom[6] = 16'hf48e;
+    rom[7] = 16'hfd92;
+    rom[8] = 16'hf760;
+    rom[9] = 16'h08ba;
+    rom[10] = 16'hfc3a;
+    rom[11] = 16'h0270;
+    rom[12] = 16'h0463;
+    rom[13] = 16'hfebb;
+    rom[14] = 16'hff6d;
+    rom[15] = 16'hf454;
+    rom[16] = 16'hff0f;
+    rom[17] = 16'h00b1;
+    rom[18] = 16'h0270;
+    rom[19] = 16'h0274;
+    rom[20] = 16'h026e;
+    rom[21] = 16'hff5a;
+    rom[22] = 16'h03cc;
+    rom[23] = 16'h031a;
   end
   initial begin
     rom_b[0] = 16'h0000;
@@ -280,54 +280,54 @@ module Create_Layer_layer0_zh #(parameter WIDTH = 16, parameter J = 1) (
   // coefficient ROM: 1 page(s) x 12x4 words
   reg signed [WIDTH-1:0] rom [0:47];
   initial begin
-    rom[0] = 16'h027d;
-    rom[1] = 16'h03cd;
-    rom[2] = 16'hf5a7;
-    rom[3] = 16'hf9d5;
-    rom[4] = 16'hfffc;
-    rom[5] = 16'h070e;
-    rom[6] = 16'hfaf3;
-    rom[7] = 16'h07c3;
-    rom[8] = 16'h0b58;
-    rom[9] = 16'h0816;
-    rom[10] = 16'h1137;
-    rom[11] = 16'hfc6d;
-    rom[12] = 16'hf11f;
-    rom[13] = 16'hf536;
-    rom[14] = 16'h0a26;
-    rom[15] = 16'hfe1a;
-    rom[16] = 16'h021d;
-    rom[17] = 16'hf23a;
-    rom[18] = 16'hfa60;
-    rom[19] = 16'hffa7;
-    rom[20] = 16'h0098;
-    rom[21] = 16'hfe1b;
-    rom[22] = 16'hff5c;
-    rom[23] = 16'h0b0b;
-    rom[24] = 16'hfffe;
-    rom[25] = 16'h0afd;
-    rom[26] = 16'hfdef;
-    rom[27] = 16'hfcb8;
-    rom[28] = 16'hfc2f;
-    rom[29] = 16'h0059;
-    rom[30] = 16'h0cd1;
-    rom[31] = 16'hf840;
-    rom[32] = 16'h046f;
-    rom[33] = 16'h059b;
-    rom[34] = 16'hf270;
-    rom[35] = 16'hf2b5;
-    rom[36] = 16'hfa46;
-    rom[37] = 16'h04ee;
-    rom[38] = 16'hfd52;
-    rom[39] = 16'hfe3b;
-    rom[40] = 16'h0377;
-    rom[41] = 16'hff88;
-    rom[42] = 16'h012a;
-    rom[43] = 16'hfcc1;
-    rom[44] = 16'h00ee;
-    rom[45] = 16'h083a;
-    rom[46] = 16'h0b36;
-    rom[47] = 16'h084f;
+    rom[0] = 16'h0475;
+    rom[1] = 16'hfcb9;
+    rom[2] = 16'h0464;
+    rom[3] = 16'hff7a;
+    rom[4] = 16'h0081;
+    rom[5] = 16'h02ac;
+    rom[6] = 16'h0b2b;
+    rom[7] = 16'h0064;
+    rom[8] = 16'h127e;
+    rom[9] = 16'h091f;
+    rom[10] = 16'h0169;
+    rom[11] = 16'h049f;
+    rom[12] = 16'hfb70;
+    rom[13] = 16'h09fb;
+    rom[14] = 16'h021f;
+    rom[15] = 16'hfe50;
+    rom[16] = 16'hfddf;
+    rom[17] = 16'hf9c7;
+    rom[18] = 16'h1141;
+    rom[19] = 16'hfb0d;
+    rom[20] = 16'hf933;
+    rom[21] = 16'hfc55;
+    rom[22] = 16'hf939;
+    rom[23] = 16'h0208;
+    rom[24] = 16'hff42;
+    rom[25] = 16'hfa9a;
+    rom[26] = 16'h0acd;
+    rom[27] = 16'hfc20;
+    rom[28] = 16'h0bee;
+    rom[29] = 16'h030a;
+    rom[30] = 16'hfcc1;
+    rom[31] = 16'h0706;
+    rom[32] = 16'h07f5;
+    rom[33] = 16'h076a;
+    rom[34] = 16'hf84c;
+    rom[35] = 16'hfaf1;
+    rom[36] = 16'h0bf3;
+    rom[37] = 16'h0fd9;
+    rom[38] = 16'h011f;
+    rom[39] = 16'h0a8d;
+    rom[40] = 16'hf856;
+    rom[41] = 16'hfa80;
+    rom[42] = 16'hfb4b;
+    rom[43] = 16'h03e8;
+    rom[44] = 16'hfcbb;
+    rom[45] = 16'hff7e;
+    rom[46] = 16'h12ae;
+    rom[47] = 16'hf622;
   end
   reg [$clog2(4):0] cyc;  // 4 serial MACC cycles (J = 1 copies)
   genvar gi, ji;
@@ -372,14 +372,14 @@ module Create_Layer_End_C #(parameter WIDTH = 16, parameter J = 1) (
   // coefficient ROM: 1 page(s) x 2x4 words
   reg signed [WIDTH-1:0] rom [0:7];
   initial begin
-    rom[0] = 16'hfceb;
-    rom[1] = 16'hf43c;
-    rom[2] = 16'h0460;
-    rom[3] = 16'h10c4;
-    rom[4] = 16'h08ef;
-    rom[5] = 16'h014a;
-    rom[6] = 16'hfbb4;
-    rom[7] = 16'hf8dd;
+    rom[0] = 16'h0a5d;
+    rom[1] = 16'h0ad7;
+    rom[2] = 16'hfcb9;
+    rom[3] = 16'hf9d3;
+    rom[4] = 16'h030c;
+    rom[5] = 16'h0027;
+    rom[6] = 16'h0499;
+    rom[7] = 16'hf9f9;
   end
   reg [$clog2(4):0] cyc;  // 4 serial MACC cycles (J = 1 copies)
   genvar gi, ji;

@@ -198,102 +198,102 @@ module Create_Layer_layer0_z #(parameter WIDTH = 16, parameter J = 1) (
   reg signed [WIDTH-1:0] rom [0:95];
   reg signed [WIDTH-1:0] rom_b [0:15];  // bias ROM, one word per lane
   initial begin
-    rom[0] = 16'hfc30;
-    rom[1] = 16'hf3d1;
-    rom[2] = 16'hfb8e;
-    rom[3] = 16'hf4e1;
-    rom[4] = 16'hffd7;
-    rom[5] = 16'hf6c5;
-    rom[6] = 16'h0912;
-    rom[7] = 16'hf879;
-    rom[8] = 16'h0f73;
-    rom[9] = 16'hf686;
-    rom[10] = 16'h0658;
-    rom[11] = 16'h05ac;
-    rom[12] = 16'h1a56;
-    rom[13] = 16'h0182;
-    rom[14] = 16'hf50e;
-    rom[15] = 16'h0bdc;
-    rom[16] = 16'h016c;
-    rom[17] = 16'h098a;
-    rom[18] = 16'hfdd5;
-    rom[19] = 16'h0525;
-    rom[20] = 16'h067d;
-    rom[21] = 16'h07c5;
-    rom[22] = 16'h02c9;
-    rom[23] = 16'h033c;
-    rom[24] = 16'h1218;
-    rom[25] = 16'hf621;
-    rom[26] = 16'hf994;
-    rom[27] = 16'h009b;
-    rom[28] = 16'h0ae6;
-    rom[29] = 16'hfebb;
-    rom[30] = 16'h041a;
-    rom[31] = 16'heb4e;
-    rom[32] = 16'hf84f;
-    rom[33] = 16'h01a4;
-    rom[34] = 16'h05da;
-    rom[35] = 16'hf7ab;
-    rom[36] = 16'hf8b6;
-    rom[37] = 16'h0488;
-    rom[38] = 16'h0717;
-    rom[39] = 16'h0588;
-    rom[40] = 16'hfdbf;
-    rom[41] = 16'h04c3;
-    rom[42] = 16'hef9d;
-    rom[43] = 16'h119c;
-    rom[44] = 16'h00e6;
-    rom[45] = 16'hfb42;
-    rom[46] = 16'hf66b;
-    rom[47] = 16'hfffe;
-    rom[48] = 16'hfc27;
-    rom[49] = 16'h06cb;
-    rom[50] = 16'h0073;
-    rom[51] = 16'hf543;
-    rom[52] = 16'h0333;
-    rom[53] = 16'h03f5;
-    rom[54] = 16'h08bf;
-    rom[55] = 16'h0ad0;
-    rom[56] = 16'hfd5d;
-    rom[57] = 16'hffb8;
-    rom[58] = 16'hfd2c;
-    rom[59] = 16'hfd4e;
-    rom[60] = 16'hf83f;
-    rom[61] = 16'hed0f;
-    rom[62] = 16'hf1cd;
-    rom[63] = 16'h088f;
-    rom[64] = 16'h0435;
-    rom[65] = 16'h08b0;
-    rom[66] = 16'hf4a9;
-    rom[67] = 16'h0109;
-    rom[68] = 16'h089c;
-    rom[69] = 16'h08ad;
-    rom[70] = 16'h0ca6;
-    rom[71] = 16'hfd00;
-    rom[72] = 16'h1533;
-    rom[73] = 16'h013b;
-    rom[74] = 16'h0f24;
-    rom[75] = 16'h0959;
-    rom[76] = 16'hffb0;
-    rom[77] = 16'h0716;
-    rom[78] = 16'he787;
-    rom[79] = 16'hf743;
-    rom[80] = 16'hf905;
-    rom[81] = 16'hff66;
-    rom[82] = 16'h0c5b;
-    rom[83] = 16'h05b7;
-    rom[84] = 16'h15ef;
-    rom[85] = 16'hef6a;
-    rom[86] = 16'h018c;
-    rom[87] = 16'hf827;
-    rom[88] = 16'hf39d;
-    rom[89] = 16'hfd9b;
-    rom[90] = 16'hee9d;
-    rom[91] = 16'h03ac;
-    rom[92] = 16'h14f8;
-    rom[93] = 16'hf930;
-    rom[94] = 16'h0dba;
-    rom[95] = 16'hf8d4;
+    rom[0] = 16'h039c;
+    rom[1] = 16'h08ba;
+    rom[2] = 16'h0475;
+    rom[3] = 16'hf9c7;
+    rom[4] = 16'hf84c;
+    rom[5] = 16'h108f;
+    rom[6] = 16'hfc96;
+    rom[7] = 16'h0270;
+    rom[8] = 16'h0081;
+    rom[9] = 16'hfc55;
+    rom[10] = 16'h011f;
+    rom[11] = 16'hf683;
+    rom[12] = 16'hf5ac;
+    rom[13] = 16'hfebb;
+    rom[14] = 16'h127e;
+    rom[15] = 16'hfa9a;
+    rom[16] = 16'hfb4b;
+    rom[17] = 16'hf173;
+    rom[18] = 16'hf48e;
+    rom[19] = 16'hf454;
+    rom[20] = 16'hfb70;
+    rom[21] = 16'h030a;
+    rom[22] = 16'h12ae;
+    rom[23] = 16'hf628;
+    rom[24] = 16'hf760;
+    rom[25] = 16'h00b1;
+    rom[26] = 16'hfddf;
+    rom[27] = 16'h076a;
+    rom[28] = 16'hff7a;
+    rom[29] = 16'hff39;
+    rom[30] = 16'hfc3a;
+    rom[31] = 16'h0274;
+    rom[32] = 16'hf933;
+    rom[33] = 16'h0fd9;
+    rom[34] = 16'h0064;
+    rom[35] = 16'hfb56;
+    rom[36] = 16'h0463;
+    rom[37] = 16'hff5a;
+    rom[38] = 16'hff42;
+    rom[39] = 16'hfa80;
+    rom[40] = 16'h049f;
+    rom[41] = 16'hefaf;
+    rom[42] = 16'hff6d;
+    rom[43] = 16'h031a;
+    rom[44] = 16'h0bee;
+    rom[45] = 16'hff7e;
+    rom[46] = 16'hfe50;
+    rom[47] = 16'h008c;
+    rom[48] = 16'hff0f;
+    rom[49] = 16'h00ba;
+    rom[50] = 16'h07f5;
+    rom[51] = 16'h0464;
+    rom[52] = 16'hfb0d;
+    rom[53] = 16'hfad9;
+    rom[54] = 16'h0270;
+    rom[55] = 16'hfced;
+    rom[56] = 16'h0bf3;
+    rom[57] = 16'h0b2b;
+    rom[58] = 16'h0208;
+    rom[59] = 16'h031f;
+    rom[60] = 16'h026e;
+    rom[61] = 16'h0731;
+    rom[62] = 16'hf856;
+    rom[63] = 16'h0169;
+    rom[64] = 16'hfc20;
+    rom[65] = 16'h0817;
+    rom[66] = 16'h03cc;
+    rom[67] = 16'h0153;
+    rom[68] = 16'hfcbb;
+    rom[69] = 16'h021f;
+    rom[70] = 16'h0706;
+    rom[71] = 16'hfbac;
+    rom[72] = 16'hf21e;
+    rom[73] = 16'hf6dc;
+    rom[74] = 16'hfcb9;
+    rom[75] = 16'h1141;
+    rom[76] = 16'hfaf1;
+    rom[77] = 16'h04f4;
+    rom[78] = 16'hf14a;
+    rom[79] = 16'h0ba0;
+    rom[80] = 16'h02ac;
+    rom[81] = 16'hf939;
+    rom[82] = 16'h0a8d;
+    rom[83] = 16'h031a;
+    rom[84] = 16'hfc28;
+    rom[85] = 16'hfe21;
+    rom[86] = 16'h091f;
+    rom[87] = 16'h0acd;
+    rom[88] = 16'h03e8;
+    rom[89] = 16'h0eac;
+    rom[90] = 16'hfd92;
+    rom[91] = 16'h02d9;
+    rom[92] = 16'h09fb;
+    rom[93] = 16'hfcc1;
+    rom[94] = 16'hf622;
+    rom[95] = 16'hf63e;
   end
   initial begin
     rom_b[0] = 16'h0000;
@@ -356,14 +356,14 @@ module Create_Layer_End_C #(parameter WIDTH = 16, parameter J = 1) (
   // coefficient ROM: 1 page(s) x 2x4 words
   reg signed [WIDTH-1:0] rom [0:7];
   initial begin
-    rom[0] = 16'hfceb;
-    rom[1] = 16'hf43c;
-    rom[2] = 16'h0460;
-    rom[3] = 16'h10c4;
-    rom[4] = 16'h08ef;
-    rom[5] = 16'h014a;
-    rom[6] = 16'hfbb4;
-    rom[7] = 16'hf8dd;
+    rom[0] = 16'h0a5d;
+    rom[1] = 16'h0ad7;
+    rom[2] = 16'hfcb9;
+    rom[3] = 16'hf9d3;
+    rom[4] = 16'h030c;
+    rom[5] = 16'h0027;
+    rom[6] = 16'h0499;
+    rom[7] = 16'hf9f9;
   end
   reg [$clog2(4):0] cyc;  // 4 serial MACC cycles (J = 1 copies)
   genvar gi, ji;

@@ -113,88 +113,88 @@ module Create_Layer_hidden_z #(parameter WIDTH = 16, parameter J = 1) (
   reg signed [WIDTH-1:0] rom [0:63];
   reg signed [WIDTH-1:0] rom_b [0:15];  // bias ROM, one word per lane
   initial begin
-    rom[0] = 16'hf976;
-    rom[1] = 16'hfc1f;
-    rom[2] = 16'h050b;
-    rom[3] = 16'hfe0b;
-    rom[4] = 16'h07e5;
-    rom[5] = 16'h062a;
-    rom[6] = 16'hef78;
-    rom[7] = 16'h0aba;
-    rom[8] = 16'hf96d;
-    rom[9] = 16'h0036;
-    rom[10] = 16'hf909;
-    rom[11] = 16'h036a;
-    rom[12] = 16'h08b5;
-    rom[13] = 16'hf923;
-    rom[14] = 16'hfa43;
-    rom[15] = 16'he84e;
-    rom[16] = 16'hfcd9;
-    rom[17] = 16'h077c;
-    rom[18] = 16'hf74b;
-    rom[19] = 16'hea08;
-    rom[20] = 16'h04f1;
-    rom[21] = 16'hf48a;
-    rom[22] = 16'hf729;
-    rom[23] = 16'h03ac;
-    rom[24] = 16'hfde5;
-    rom[25] = 16'hfc20;
-    rom[26] = 16'h02fd;
-    rom[27] = 16'hfead;
-    rom[28] = 16'hfdcb;
-    rom[29] = 16'hf8b6;
-    rom[30] = 16'hf22b;
-    rom[31] = 16'hf530;
-    rom[32] = 16'h0dd2;
-    rom[33] = 16'h0989;
-    rom[34] = 16'h0af6;
-    rom[35] = 16'hf42d;
-    rom[36] = 16'hfef4;
-    rom[37] = 16'hf2eb;
-    rom[38] = 16'hfcb6;
-    rom[39] = 16'hfbfe;
-    rom[40] = 16'h01df;
-    rom[41] = 16'hfb8c;
-    rom[42] = 16'h022e;
-    rom[43] = 16'hff5e;
-    rom[44] = 16'h039e;
-    rom[45] = 16'hf257;
-    rom[46] = 16'h01dc;
-    rom[47] = 16'h0686;
-    rom[48] = 16'h0e59;
-    rom[49] = 16'hf876;
-    rom[50] = 16'hfc53;
-    rom[51] = 16'h031d;
-    rom[52] = 16'hfa0c;
-    rom[53] = 16'hfba1;
-    rom[54] = 16'hf343;
-    rom[55] = 16'hfe72;
-    rom[56] = 16'hfcee;
-    rom[57] = 16'hfd4f;
-    rom[58] = 16'h02a3;
-    rom[59] = 16'hf72e;
-    rom[60] = 16'hfb06;
-    rom[61] = 16'hfb28;
-    rom[62] = 16'h06c7;
-    rom[63] = 16'h0a63;
+    rom[0] = 16'h0197;
+    rom[1] = 16'hf9d7;
+    rom[2] = 16'hf5ee;
+    rom[3] = 16'hfcb7;
+    rom[4] = 16'hf7f4;
+    rom[5] = 16'h024b;
+    rom[6] = 16'h07f5;
+    rom[7] = 16'h03fd;
+    rom[8] = 16'hfa0d;
+    rom[9] = 16'h091c;
+    rom[10] = 16'hffec;
+    rom[11] = 16'h0399;
+    rom[12] = 16'hfd9e;
+    rom[13] = 16'hfbec;
+    rom[14] = 16'h0237;
+    rom[15] = 16'h05fd;
+    rom[16] = 16'hfe5c;
+    rom[17] = 16'hf93d;
+    rom[18] = 16'h07ce;
+    rom[19] = 16'h044c;
+    rom[20] = 16'hf862;
+    rom[21] = 16'h072f;
+    rom[22] = 16'h046d;
+    rom[23] = 16'hef2a;
+    rom[24] = 16'hf436;
+    rom[25] = 16'h006a;
+    rom[26] = 16'h0c97;
+    rom[27] = 16'hf8e2;
+    rom[28] = 16'hfec2;
+    rom[29] = 16'hfe79;
+    rom[30] = 16'h0351;
+    rom[31] = 16'hffe2;
+    rom[32] = 16'hef12;
+    rom[33] = 16'h06a1;
+    rom[34] = 16'h09f4;
+    rom[35] = 16'h075e;
+    rom[36] = 16'h0de0;
+    rom[37] = 16'hfcd6;
+    rom[38] = 16'h02d8;
+    rom[39] = 16'hfff6;
+    rom[40] = 16'h0d42;
+    rom[41] = 16'h1177;
+    rom[42] = 16'h010e;
+    rom[43] = 16'h0337;
+    rom[44] = 16'hf8f4;
+    rom[45] = 16'hfe18;
+    rom[46] = 16'hfc8a;
+    rom[47] = 16'h0365;
+    rom[48] = 16'hfa4b;
+    rom[49] = 16'h1000;
+    rom[50] = 16'h02f0;
+    rom[51] = 16'h045c;
+    rom[52] = 16'hffca;
+    rom[53] = 16'h0920;
+    rom[54] = 16'hfd64;
+    rom[55] = 16'h02bd;
+    rom[56] = 16'h0918;
+    rom[57] = 16'h0639;
+    rom[58] = 16'h0943;
+    rom[59] = 16'h0eac;
+    rom[60] = 16'hfd69;
+    rom[61] = 16'h06ca;
+    rom[62] = 16'hf7d6;
+    rom[63] = 16'hed05;
   end
   initial begin
-    rom_b[0] = 16'h00c3;
-    rom_b[1] = 16'hff51;
-    rom_b[2] = 16'h0381;
-    rom_b[3] = 16'h0230;
-    rom_b[4] = 16'h00f0;
-    rom_b[5] = 16'hffe3;
-    rom_b[6] = 16'h000d;
-    rom_b[7] = 16'hfed3;
-    rom_b[8] = 16'h008e;
-    rom_b[9] = 16'hff66;
-    rom_b[10] = 16'hfdf8;
-    rom_b[11] = 16'h002a;
-    rom_b[12] = 16'hfe8e;
-    rom_b[13] = 16'hfd97;
-    rom_b[14] = 16'h0048;
-    rom_b[15] = 16'h0188;
+    rom_b[0] = 16'hfddd;
+    rom_b[1] = 16'hff70;
+    rom_b[2] = 16'h007f;
+    rom_b[3] = 16'hfe66;
+    rom_b[4] = 16'hffe0;
+    rom_b[5] = 16'hfe8b;
+    rom_b[6] = 16'h006d;
+    rom_b[7] = 16'h02e7;
+    rom_b[8] = 16'hff9c;
+    rom_b[9] = 16'hfe7a;
+    rom_b[10] = 16'h01aa;
+    rom_b[11] = 16'h018d;
+    rom_b[12] = 16'h0259;
+    rom_b[13] = 16'h0020;
+    rom_b[14] = 16'hff56;
+    rom_b[15] = 16'h013b;
   end
   reg [$clog2(4):0] cyc;  // 4 serial MACC cycles (J = 1 copies)
   genvar gi, ji;
@@ -239,18 +239,18 @@ module Create_Layer_beta #(parameter WIDTH = 16, parameter J = 1) (
   // coefficient ROM: 1 page(s) x 4x3 words
   reg signed [WIDTH-1:0] rom [0:11];
   initial begin
-    rom[0] = 16'hebb2;
-    rom[1] = 16'h01f2;
-    rom[2] = 16'hfc29;
-    rom[3] = 16'h0b46;
-    rom[4] = 16'h0bb7;
-    rom[5] = 16'h00f2;
-    rom[6] = 16'h037b;
-    rom[7] = 16'hff39;
-    rom[8] = 16'hf150;
-    rom[9] = 16'h0646;
-    rom[10] = 16'h09eb;
-    rom[11] = 16'hfe76;
+    rom[0] = 16'h0946;
+    rom[1] = 16'hf7a1;
+    rom[2] = 16'hf917;
+    rom[3] = 16'hf52e;
+    rom[4] = 16'hf7f4;
+    rom[5] = 16'h0571;
+    rom[6] = 16'h06b0;
+    rom[7] = 16'hf687;
+    rom[8] = 16'h0f59;
+    rom[9] = 16'hee88;
+    rom[10] = 16'hf418;
+    rom[11] = 16'h013c;
   end
   reg [$clog2(3):0] cyc;  // 3 serial MACC cycles (J = 1 copies)
   genvar gi, ji;
@@ -295,14 +295,14 @@ module Create_Layer_End_C #(parameter WIDTH = 16, parameter J = 1) (
   // coefficient ROM: 1 page(s) x 2x4 words
   reg signed [WIDTH-1:0] rom [0:7];
   initial begin
-    rom[0] = 16'hfceb;
-    rom[1] = 16'hf43c;
-    rom[2] = 16'h0460;
-    rom[3] = 16'h10c4;
-    rom[4] = 16'h08ef;
-    rom[5] = 16'h014a;
-    rom[6] = 16'hfbb4;
-    rom[7] = 16'hf8dd;
+    rom[0] = 16'h0a5d;
+    rom[1] = 16'h0ad7;
+    rom[2] = 16'hfcb9;
+    rom[3] = 16'hf9d3;
+    rom[4] = 16'h030c;
+    rom[5] = 16'h0027;
+    rom[6] = 16'h0499;
+    rom[7] = 16'hf9f9;
   end
   reg [$clog2(4):0] cyc;  // 4 serial MACC cycles (J = 1 copies)
   genvar gi, ji;

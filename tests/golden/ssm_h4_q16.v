@@ -28,14 +28,14 @@ module Create_Layer_layer0_drive #(parameter WIDTH = 16, parameter J = 1) (
   reg signed [WIDTH-1:0] rom [0:7];
   reg signed [WIDTH-1:0] rom_b [0:3];  // bias ROM, one word per lane
   initial begin
-    rom[0] = 16'hf116;
-    rom[1] = 16'hf941;
-    rom[2] = 16'hff8b;
-    rom[3] = 16'h08d1;
-    rom[4] = 16'hef7e;
-    rom[5] = 16'h159a;
-    rom[6] = 16'hec05;
-    rom[7] = 16'hffca;
+    rom[0] = 16'h064e;
+    rom[1] = 16'hfcfd;
+    rom[2] = 16'h00b6;
+    rom[3] = 16'hf661;
+    rom[4] = 16'h1a27;
+    rom[5] = 16'hfef4;
+    rom[6] = 16'hf98c;
+    rom[7] = 16'h10df;
   end
   initial begin
     rom_b[0] = 16'h0000;
@@ -86,14 +86,14 @@ module Create_Layer_End_C #(parameter WIDTH = 16, parameter J = 1) (
   // coefficient ROM: 1 page(s) x 2x4 words
   reg signed [WIDTH-1:0] rom [0:7];
   initial begin
-    rom[0] = 16'hfceb;
-    rom[1] = 16'hf43c;
-    rom[2] = 16'h0460;
-    rom[3] = 16'h10c4;
-    rom[4] = 16'h08ef;
-    rom[5] = 16'h014a;
-    rom[6] = 16'hfbb4;
-    rom[7] = 16'hf8dd;
+    rom[0] = 16'h0a5d;
+    rom[1] = 16'h0ad7;
+    rom[2] = 16'hfcb9;
+    rom[3] = 16'hf9d3;
+    rom[4] = 16'h030c;
+    rom[5] = 16'h0027;
+    rom[6] = 16'h0499;
+    rom[7] = 16'hf9f9;
   end
   reg [$clog2(4):0] cyc;  // 4 serial MACC cycles (J = 1 copies)
   genvar gi, ji;
@@ -142,7 +142,7 @@ module Create_Datapath_layer0 #(parameter WIDTH = 16) (
   reg signed [4*WIDTH-1:0] r_h;  // state register
   wire signed [4*WIDTH-1:0] w_h;  assign w_h = r_h;
   // const ROM 'a' [1x4]
-  wire signed [4*WIDTH-1:0] w_a = {16'h08ad, 16'h0cdb, 16'h08bc, 16'h0ad9};
+  wire signed [4*WIDTH-1:0] w_a = {16'h091f, 16'h094d, 16'h0abf, 16'h0c80};
   // const ROM 'w_in' [2x4]
   // const ROM 'b' [1x4]
   wire signed [4*WIDTH-1:0] w_drive;

@@ -15,7 +15,8 @@ from repro.launch import steps as steps_lib
 from repro.models import lm
 from repro.models.config import ShapeSpec
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 3)
 
 for arch in ("smollm-135m", "olmoe-1b-7b", "zamba2-1.2b"):
     cfg = dataclasses.replace(get_smoke_config(arch), remat=False)

@@ -7,7 +7,8 @@ SYNTH_TP_OK     — xla backend with mesh (dp=4, tp=2): the [D+H, 4H] gate
                   changes the reduction order, so allclose, not bitwise).
 SYNTH_PALLAS_OK — pallas backend under shard_map over "data": each shard
                   folds its local C-slow streams into its own kernel grid;
-                  outputs match the unsharded fused kernel.
+                  outputs match the unsharded fused kernel, also for a
+                  stream count that does not divide the data axis.
 SYNTH_CACHE_OK  — synthesize(mesh=...) forks the memo + ledger keys (no
                   aliasing against the single-device artifact).
 """
@@ -53,6 +54,9 @@ p1 = pallas_backend.compile_program(prog_c, mesh=mesh)
 yc0 = np.asarray(jax.jit(p0)(prog_c.params, uc))
 yc1 = np.asarray(jax.jit(p1)(prog_c.params, uc))
 np.testing.assert_allclose(yc1, yc0, atol=1e-5)
+# a leading axis that does not divide dp=4 is zero-padded over all shards
+yc3 = np.asarray(jax.jit(p1)(prog_c.params, uc[:3]))
+np.testing.assert_allclose(yc3, yc0[:3], atol=1e-5)
 print("SYNTH_PALLAS_OK")
 
 r0 = synthesize(spec, batch=8, backend="xla", measure=False)

@@ -489,6 +489,23 @@ def test_synth_fallback_disabled_raises():
     synthesize_cache_clear()
 
 
+def test_synth_non_transient_error_is_reraised(monkeypatch):
+    """A compile error that is not a TransientFault (e.g. the device's
+    compiler refusing a kernel) surfaces instead of degrading to xla."""
+    from repro.core import synthesis
+    from repro.core.synthesis import synthesize, synthesize_cache_clear
+
+    def refuse(program, spec, backend, *a, **kw):
+        raise RuntimeError(f"{backend}: scoped vmem exceeded")
+
+    synthesize_cache_clear()
+    monkeypatch.setattr(synthesis, "_build_fwd", refuse)
+    with pytest.raises(RuntimeError, match="pallas: scoped vmem"):
+        synthesize(_tiny_spec(), batch=2, backend="pallas", measure=False,
+                   backoff_s=0.0)
+    synthesize_cache_clear()
+
+
 def test_synth_ref_backend_matches_xla():
     from repro.core.synthesis import synthesize, synthesize_cache_clear
 

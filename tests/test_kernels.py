@@ -174,3 +174,30 @@ def test_tanh_lut_saturation():
     x = jnp.asarray([-100.0, -4.0, 4.0, 100.0])
     y = tanh_lut(x, lut, block=4)
     np.testing.assert_allclose(y, jnp.tanh(x), atol=2e-3)
+
+
+# ---------------------------------------------------------------------------
+# platform: interpret mode and the scoped-VMEM request
+# ---------------------------------------------------------------------------
+
+def test_interpret_mode_follows_platform():
+    import jax
+
+    from repro.kernels.platform import resolve_interpret
+
+    assert resolve_interpret() is (jax.default_backend() != "tpu")
+    assert resolve_interpret(False) is False
+    assert resolve_interpret(True) is True
+
+
+def test_vmem_limit_counts_tiled_blocks():
+    from repro.kernels.platform import (DEFAULT_SCOPED_VMEM, vmem_bytes,
+                                        vmem_limit)
+
+    # a [1, 1024] f32 row occupies a full 8-sublane tile; int8 tiles are 32
+    assert vmem_bytes((1, 1024), jnp.float32) == 8 * 1024 * 4
+    assert vmem_bytes((1, 1024), jnp.int8) == 32 * 1024
+    w = vmem_bytes((2048, 4096), jnp.float32)        # paper-lstm gate ROM
+    assert w == 32 << 20
+    assert vmem_limit(0) == DEFAULT_SCOPED_VMEM
+    assert vmem_limit(w) > w

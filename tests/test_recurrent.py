@@ -140,7 +140,8 @@ def _kernel_case(Bsz, T, D, H, dtype=jnp.float32, seed=0):
 
 
 @pytest.mark.parametrize("Bsz,T,D,H", [(1, 16, 8, 8), (2, 32, 16, 24),
-                                       (3, 48, 12, 16), (4, 64, 32, 32)])
+                                       (3, 48, 12, 16), (4, 64, 32, 32),
+                                       (1, 37, 8, 8), (9, 37, 8, 16)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_lstm_kernel_matches_ref(Bsz, T, D, H, dtype):
     from repro.kernels.lstm_cell.ops import lstm_seq, lstm_seq_ref

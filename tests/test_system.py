@@ -116,3 +116,24 @@ def test_sharding_specs_cover_all_params(arch):
     l_remat, _ = lm.train_loss(p_real, dataclasses.replace(cfg, remat=True), batch)
     l_plain, _ = lm.train_loss(p_real, dataclasses.replace(cfg, remat=False), batch)
     np.testing.assert_allclose(float(l_remat), float(l_plain), rtol=1e-5)
+
+
+def test_compile_cache_dir_env_wins_else_repo_path(monkeypatch):
+    import jax
+
+    from repro.launch import compile_cache
+
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv(compile_cache.ENV, "/elsewhere/cache")
+        jax.config.update("jax_compilation_cache_dir", None)
+        assert compile_cache.enable() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir is None   # JAX reads the env
+        monkeypatch.delenv(compile_cache.ENV)
+        assert compile_cache.enable() == str(compile_cache.DEFAULT_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(
+            compile_cache.DEFAULT_DIR)
+        assert compile_cache.DEFAULT_DIR.parent == compile_cache.REPO_ROOT
+        assert (compile_cache.REPO_ROOT / "chip_smoke.py").exists()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)

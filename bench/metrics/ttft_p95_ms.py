@@ -1,0 +1,8 @@
+"""95th percentile time to first token of the requests due in the window,
+timed from when each was due (ms)."""
+
+from bench.stats import pct, ttft_ms
+
+
+def read(run):
+    return pct(ttft_ms(run), 95)

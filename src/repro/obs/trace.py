@@ -1,4 +1,5 @@
-"""Span tracing with Chrome-trace-event / Perfetto JSON export.
+"""Span tracing with Chrome-trace-event / Perfetto JSON export, and the same
+spans on the JAX profiler's clock.
 
 The tracer records *host-side* structure only: dispatch boundaries, device
 syncs, compile phases, per-request lifecycles.  Nothing here may be called
@@ -9,6 +10,13 @@ jaxpr and re-trigger tracing on every enable/disable flip).
 Disabled (the default) is a near-no-op: ``span()`` returns a shared null
 context manager after one attribute check, and every other record method
 returns after the same check — no allocation, no locking, no clock read.
+
+An enabled ``span(name, args=...)`` also opens
+``jax.profiler.TraceAnnotation("repro." + name, **args)`` for its lifetime:
+whenever a profiler session is running, every span lands on the profiler's
+host plane, on the device trace's clock, with ``args`` as event stats (an
+annotation costs well under a microsecond while no session runs).  The
+retroactive records (``complete``, ``instant``, ``counter``) are JSON only.
 
 Export is the Chrome trace-event JSON array format (``{"traceEvents":
 [...]}``), loadable in Perfetto (https://ui.perfetto.dev) and
@@ -33,6 +41,10 @@ import json
 import threading
 import time
 
+from jax.profiler import TraceAnnotation
+
+PROFILER_PREFIX = "repro."
+
 
 class _NullSpan:
     """Reusable, reentrant no-op context manager."""
@@ -50,13 +62,16 @@ _NULL = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("_tr", "name", "cat", "tid", "args", "t0")
+    __slots__ = ("_tr", "name", "cat", "tid", "args", "t0", "_ann")
 
     def __init__(self, tr: "Tracer", name: str, cat: str, tid: int, args):
         self._tr, self.name, self.cat, self.tid, self.args = \
             tr, name, cat, tid, args
 
     def __enter__(self):
+        self._ann = TraceAnnotation(PROFILER_PREFIX + self.name,
+                                    **(self.args or {}))
+        self._ann.__enter__()
         self.t0 = self._tr.now_us()
         return self
 
@@ -64,6 +79,7 @@ class _Span:
         tr = self._tr
         tr.complete(self.name, self.t0, tr.now_us() - self.t0,
                     cat=self.cat, tid=self.tid, args=self.args)
+        self._ann.__exit__(*exc)
         return False
 
 
@@ -94,7 +110,8 @@ class Tracer:
 
     def span(self, name: str, *, cat: str = "repro", tid: int = 0,
              args: dict | None = None):
-        """Context manager recording one complete ('X') event."""
+        """Context manager recording one complete ('X') event, and the
+        ``repro.<name>`` profiler annotation around the same code."""
         if not self.enabled:
             return _NULL
         return _Span(self, name, cat, tid, args)

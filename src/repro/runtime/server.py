@@ -301,16 +301,12 @@ class DecodeServer:
             "decode ticks aborted on a transient dispatch error")
         self._m_stalled = m.counter(
             "server_stalled", "watchdog firings (no progress in bound)")
-        # per-shard telemetry: token counters labeled shard=N, and one trace
-        # track per data shard (tid = 10_000 + s) for live-slot counters
+        # per-shard telemetry: token counters labeled shard=N
         self._m_tokens_shard = (
             [m.counter("decoded_tokens_shard",
                        "tokens generated by data shard", shard=s)
              for s in range(self.dp)]
             if plan is not None else None)
-        if plan is not None and self._tr.enabled:
-            for s in range(self.dp):
-                self._tr.thread_name(10_000 + s, f"shard {s}")
         self._tick_prompt_steps = 0
         self._tick_uncontended = True       # no slot is live before tick 0
 
@@ -672,22 +668,34 @@ class DecodeServer:
             out["faults"] = plan.report()
         return out
 
-    def _start_request(self, req: Request, b: int, first_logits: np.ndarray) -> None:
+    def _start_request(self, req: Request, b: int,
+                       first_logits: jax.Array | np.ndarray) -> None:
         """Go live after the prompt state is in slot ``b`` — or retire at
         admission when the token budget is already met by the prefill-sampled
-        first token (the max_new_tokens=1 off-by-one fix)."""
-        first = int(np.argmax(first_logits))
-        now = time.perf_counter()
-        req.out_tokens.append(first)
-        req.first_token_at = now
-        hit_eos = self.eos_id is not None and first == self.eos_id
-        if len(req.out_tokens) >= req.max_new_tokens or hit_eos:
-            self._retire(req, now, "eos" if hit_eos else "max_tokens")
-            return
-        self.slot_req[b] = req
-        self.live[b] = True
-        self.pos[b] = len(req.prompt)
-        self.cur_tokens[b] = first
+        first token (the max_new_tokens=1 off-by-one fix).  ``first_logits``
+        may still be on the device: fetching it is where the host waits for
+        the prefill."""
+        with self._tr.span("first_token", cat="admit", args={"uid": req.uid}):
+            first = int(np.argmax(np.asarray(first_logits)))
+            now = time.perf_counter()
+            req.out_tokens.append(first)
+            req.first_token_at = now
+            hit_eos = self.eos_id is not None and first == self.eos_id
+            if len(req.out_tokens) >= req.max_new_tokens or hit_eos:
+                self._retire(req, now, "eos" if hit_eos else "max_tokens")
+                return
+            self.slot_req[b] = req
+            self.live[b] = True
+            self.pos[b] = len(req.prompt)
+            self.cur_tokens[b] = first
+
+    def _splice(self, uid: int, b: int, prefill_caches: PyTree,
+                plen: int) -> None:
+        """Write a B=1 prompt state into slot ``b`` of the decode caches."""
+        with self._tr.span("splice", cat="admit", args={"uid": uid}):
+            self.caches = splice_cache(self.caches,
+                                       self._to_mesh(prefill_caches), b,
+                                       plen, self.S)
 
     def _chunk_fn(self, c: int) -> Callable:
         fn = self._chunk_fns.get(c)
@@ -762,91 +770,89 @@ class DecodeServer:
         the production continuous-batching pattern (separate prefill
         program, shared decode program; other slots' states are untouched).
         """
-        while True:
-            if self._free_slot() is None:
-                return
-            req = self.scheduler.next_request()
-            if req is None:
-                return
-            now = time.perf_counter()
-            if req.max_new_tokens <= 0:
-                # budget already met: retire before spending any device work
-                self._retire(req, now, "max_tokens")
-                continue
-            plen = len(req.prompt)
-            if self.plan is None:
-                shard = 0
-                b = self._free_slot()
-            else:
-                shard = self._place(req)
-                b = self._free_slot(shard=shard)
-                self.scheduler.record_placement(req, shard)
-            pc = self._pc(shard)
-
-            entry = None
-            if pc is not None:
-                candidates = pc.lookup(req.prompt)
-                full = next((e for e in candidates
-                             if e.length == plen and e.logits is not None), None)
-                if full is not None:
-                    self.caches = splice_cache(self.caches,
-                                               self._to_mesh(full.caches), b,
-                                               plen, self.S)
-                    spec = self._fire("prefix.splice")
-                    if spec is not None:
-                        # corrupted checkpoint splice: caught downstream by
-                        # the per-slot non-finite detection, not here
-                        self._poison_slot(b, spec.mode)
-                    req.prefix_hit_tokens = plen
-                    pc.record_hit(plen, full=True)
-                    self._start_request(req, b, np.asarray(full.logits))
+        with self._tr.span("admit", cat="admit"):
+            while True:
+                if self._free_slot() is None:
+                    return
+                req = self.scheduler.next_request()
+                if req is None:
+                    return
+                now = time.perf_counter()
+                if req.max_new_tokens <= 0:
+                    # budget already met: retire before spending any device work
+                    self._retire(req, now, "max_tokens")
                     continue
+                plen = len(req.prompt)
+                if self.plan is None:
+                    shard = 0
+                    b = self._free_slot()
+                else:
+                    shard = self._place(req)
+                    b = self._free_slot(shard=shard)
+                    self.scheduler.record_placement(req, shard)
+                pc = self._pc(shard)
+
+                entry = None
+                if pc is not None:
+                    candidates = pc.lookup(req.prompt)
+                    full = next((e for e in candidates
+                                 if e.length == plen and e.logits is not None), None)
+                    if full is not None:
+                        self._splice(req.uid, b, full.caches, plen)
+                        spec = self._fire("prefix.splice")
+                        if spec is not None:
+                            # corrupted checkpoint splice: caught downstream by
+                            # the per-slot non-finite detection, not here
+                            self._poison_slot(b, spec.mode)
+                        req.prefix_hit_tokens = plen
+                        pc.record_hit(plen, full=True)
+                        self._start_request(req, b, full.logits)
+                        continue
+                    if self.prefill_chunk > 0:
+                        entry = next((e for e in candidates if e.resumable), None)
+
                 if self.prefill_chunk > 0:
-                    entry = next((e for e in candidates if e.resumable), None)
+                    # adaptive uncontended admission: with no live slot to stall
+                    # and no resumable prefix state to splice, the chunk job
+                    # machinery only adds work (resumable chunks scan against
+                    # the full [1, S] cache buffer; one-shot prefill touches
+                    # [1, plen]) — fall through to the one-shot path, which is
+                    # dispatch-identical to an unchunked server
+                    adaptive_oneshot = (self.prefill_adaptive and entry is None
+                                        and self._tick_uncontended
+                                        and not self._jobs)
+                    if not adaptive_oneshot:
+                        # job states live on the mesh (replicated) so chunk fns
+                        # consuming the mesh-sharded params never mix device sets
+                        caches = self._to_mesh(
+                            self._inflate_entry(entry) if entry is not None
+                            else lm.init_cache(self.cfg, 1, self.S))
+                        start = entry.length if entry is not None else 0
+                        if pc is not None:
+                            if entry is not None:
+                                req.prefix_hit_tokens = start
+                                pc.record_hit(start, full=False)
+                            else:
+                                pc.record_miss()
+                        self.reserved[b] = True
+                        self._jobs.append(_PrefillJob(req=req, slot=b,
+                                                      caches=caches, pos=start))
+                        continue
 
-            if self.prefill_chunk > 0:
-                # adaptive uncontended admission: with no live slot to stall
-                # and no resumable prefix state to splice, the chunk job
-                # machinery only adds work (resumable chunks scan against
-                # the full [1, S] cache buffer; one-shot prefill touches
-                # [1, plen]) — fall through to the one-shot path, which is
-                # dispatch-identical to an unchunked server
-                adaptive_oneshot = (self.prefill_adaptive and entry is None
-                                    and self._tick_uncontended
-                                    and not self._jobs)
-                if not adaptive_oneshot:
-                    # job states live on the mesh (replicated) so chunk fns
-                    # consuming the mesh-sharded params never mix device sets
-                    caches = self._to_mesh(
-                        self._inflate_entry(entry) if entry is not None
-                        else lm.init_cache(self.cfg, 1, self.S))
-                    start = entry.length if entry is not None else 0
-                    if pc is not None:
-                        if entry is not None:
-                            req.prefix_hit_tokens = start
-                            pc.record_hit(start, full=False)
-                        else:
-                            pc.record_miss()
-                    self.reserved[b] = True
-                    self._jobs.append(_PrefillJob(req=req, slot=b,
-                                                  caches=caches, pos=start))
-                    continue
-
-            # legacy one-shot prefill
-            if pc is not None:
-                pc.record_miss()
-            toks = jnp.asarray(np.array(req.prompt, np.int32)[None])
-            with self._tr.span("prefill_oneshot", cat="prefill",
-                               args={"uid": req.uid, "tokens": plen}):
-                logits, pcaches = self._prefill(self.params, toks)
-            self._m_prompt_steps.inc(plen)
-            self._tick_prompt_steps += plen
-            self.caches = splice_cache(self.caches, self._to_mesh(pcaches),
-                                       b, plen, self.S)
-            if pc is not None:
-                pc.insert(req.prompt, pcaches, logits=logits[0],
-                          resumable=False)
-            self._start_request(req, b, np.asarray(logits[0]))
+                # legacy one-shot prefill
+                if pc is not None:
+                    pc.record_miss()
+                toks = jnp.asarray(np.array(req.prompt, np.int32)[None])
+                with self._tr.span("prefill_oneshot", cat="prefill",
+                                   args={"uid": req.uid, "tokens": plen}):
+                    logits, pcaches = self._prefill(self.params, toks)
+                self._m_prompt_steps.inc(plen)
+                self._tick_prompt_steps += plen
+                self._splice(req.uid, b, pcaches, plen)
+                if pc is not None:
+                    pc.insert(req.prompt, pcaches, logits=logits[0],
+                              resumable=False)
+                self._start_request(req, b, logits[0])
 
     # ------------------------------------------------------------------
     # chunked prefill
@@ -890,43 +896,34 @@ class DecodeServer:
             self._cache_boundary(job)
             if job.pos >= plen:
                 self._jobs.remove(job)
-                self.caches = splice_cache(self.caches,
-                                           self._to_mesh(job.caches),
-                                           job.slot, plen, self.S)
+                self._splice(job.req.uid, job.slot, job.caches, plen)
                 self.reserved[job.slot] = False
-                self._start_request(job.req, job.slot,
-                                    np.asarray(job.logits[0]))
+                self._start_request(job.req, job.slot, job.logits[0])
             else:
                 self._job_rr += 1
 
     def _begin_tick(self) -> None:
-        self._tick_prompt_steps = 0
-        spec = self._fire("tick.slow")
-        if spec is not None and spec.delay_s > 0:
-            time.sleep(spec.delay_s)
-        # scrub quarantined slots (deferred device work) and reap expired
-        # requests BEFORE admission — freed slots are reused this same tick
-        self._scrub_quarantined()
-        self._reap_deadlines(time.perf_counter())
-        # contention is a tick-level property, captured before admissions:
-        # a slot is "live" here iff it was decoding when the tick began —
-        # requests started later this tick never stalled on this tick's
-        # prefill work, so that work doesn't count against the chunk bound
-        self._tick_uncontended = not self.live.any()
-        self._admit()
-        self._advance_prefill()
-        self._admit()   # full-hit admissions may free the tick for decode
-        self._m_tick_max.set_max(self._tick_prompt_steps)
-        if not self._tick_uncontended:
-            self._m_tick_contended.set_max(self._tick_prompt_steps)
-        self._m_live.set(int(self.live.sum()))
-        if self.plan is not None and self._tr.enabled:
-            for s in range(self.dp):
-                self._tr.counter(
-                    "live_slots",
-                    {"live": sum(int(self.live[b]) for b in
-                                 self.plan.slots_of_shard(s, self.B))},
-                    tid=10_000 + s)
+        with self._tr.span("begin_tick", cat="tick"):
+            self._tick_prompt_steps = 0
+            spec = self._fire("tick.slow")
+            if spec is not None and spec.delay_s > 0:
+                time.sleep(spec.delay_s)
+            # scrub quarantined slots (deferred device work) and reap expired
+            # requests BEFORE admission — freed slots are reused this same tick
+            self._scrub_quarantined()
+            self._reap_deadlines(time.perf_counter())
+            # contention is a tick-level property, captured before admissions:
+            # a slot is "live" here iff it was decoding when the tick began —
+            # requests started later this tick never stalled on this tick's
+            # prefill work, so that work doesn't count against the chunk bound
+            self._tick_uncontended = not self.live.any()
+            self._admit()
+            self._advance_prefill()
+            self._admit()   # full-hit admissions may free the tick for decode
+            self._m_tick_max.set_max(self._tick_prompt_steps)
+            if not self._tick_uncontended:
+                self._m_tick_contended.set_max(self._tick_prompt_steps)
+            self._m_live.set(int(self.live.sum()))
 
     # ------------------------------------------------------------------
     # decode drivers
@@ -1069,25 +1066,27 @@ class DecodeServer:
         self._begin_tick()
         if not self.live.any():
             return 0
-        spec = self._fire("decode.nan_carry") or self._fire("decode.nan_logits")
-        if spec is not None:
-            # the persistent driver samples on device, so both poison points
-            # inject into the carry — the in-block finite check catches it
-            b = self._fault_slot(spec)
-            if b is not None:
-                self._poison_slot(b, spec.mode)
-        k = self.block_k
-        fn = self._block_fns.get(k)
-        if fn is None:
-            fn = self._block_fns[k] = self._make_block_fn(k)
-        temps = np.array(
-            [r.temperature if r is not None else 0.0 for r in self.slot_req],
-            np.float32)
-        remaining = np.array(
-            [r.max_new_tokens - len(r.out_tokens) if r is not None else 0
-             for r in self.slot_req], np.int32)
+        live_n = int(self.live.sum())
+        with self._tr.span("block_prep", cat="decode", args={"live": live_n}):
+            spec = self._fire("decode.nan_carry") or self._fire("decode.nan_logits")
+            if spec is not None:
+                # the persistent driver samples on device, so both poison points
+                # inject into the carry — the in-block finite check catches it
+                b = self._fault_slot(spec)
+                if b is not None:
+                    self._poison_slot(b, spec.mode)
+            k = self.block_k
+            fn = self._block_fns.get(k)
+            if fn is None:
+                fn = self._block_fns[k] = self._make_block_fn(k)
+            temps = np.array(
+                [r.temperature if r is not None else 0.0 for r in self.slot_req],
+                np.float32)
+            remaining = np.array(
+                [r.max_new_tokens - len(r.out_tokens) if r is not None else 0
+                 for r in self.slot_req], np.int32)
         with self._tr.span("decode_block", cat="decode",
-                           args={"live": int(self.live.sum()), "k": k}):
+                           args={"live": live_n, "k": k}):
             try:
                 if self._fire("decode.dispatch") is not None:
                     raise TransientFault("injected decode.dispatch fault")
@@ -1110,42 +1109,44 @@ class DecodeServer:
                 self.cur_tokens = np.array(cur)   # np.array copies: the host
                 self.pos = np.array(pos)          # mirrors stay writable for
                 self.live = np.array(live)        # _admit()
-        self._m_syncs.inc()
-        now = time.perf_counter()
-        # quarantine pass: a slot that went non-finite at inner tick t
-        # produced garbage from t on — drop those emissions (and any bogus
-        # device-side retirement) and retire the slot as error:nonfinite
-        quarantine: list[int] = []
-        for b in range(self.B):
-            bad = emitted[:, b] & ~finite[:, b]
-            if bad.any():
-                tb = int(np.argmax(bad))
-                emitted[tb:, b] = False
-                done_now[tb:, b] = False
-                quarantine.append(b)
-        for t in range(k):
+        with self._tr.span("post_block", cat="decode",
+                           args={"emitted": int(emitted.sum())}):
+            self._m_syncs.inc()
+            now = time.perf_counter()
+            # quarantine pass: a slot that went non-finite at inner tick t
+            # produced garbage from t on — drop those emissions (and any bogus
+            # device-side retirement) and retire the slot as error:nonfinite
+            quarantine: list[int] = []
             for b in range(self.B):
-                if not emitted[t, b]:
-                    continue
-                req = self.slot_req[b]
-                req.out_tokens.append(int(toks[t, b]))
-                self._m_tokens.inc()
-                if self._m_tokens_shard is not None:
-                    self._m_tokens_shard[self._shard_of(b)].inc()
-                if req.first_token_at is None:
-                    req.first_token_at = now
-                if done_now[t, b]:
-                    nxt = int(toks[t, b])
-                    reason = ("eos" if (self.eos_id is not None
-                                        and nxt == self.eos_id) else
-                              ("max_tokens"
-                               if len(req.out_tokens) >= req.max_new_tokens
-                               else "out_of_cache"))
-                    self._retire(req, now, reason)
-                    self.slot_req[b] = None
-        for b in quarantine:
-            self._quarantine(b, now)
-        return int(self.live.sum())
+                bad = emitted[:, b] & ~finite[:, b]
+                if bad.any():
+                    tb = int(np.argmax(bad))
+                    emitted[tb:, b] = False
+                    done_now[tb:, b] = False
+                    quarantine.append(b)
+            for t in range(k):
+                for b in range(self.B):
+                    if not emitted[t, b]:
+                        continue
+                    req = self.slot_req[b]
+                    req.out_tokens.append(int(toks[t, b]))
+                    self._m_tokens.inc()
+                    if self._m_tokens_shard is not None:
+                        self._m_tokens_shard[self._shard_of(b)].inc()
+                    if req.first_token_at is None:
+                        req.first_token_at = now
+                    if done_now[t, b]:
+                        nxt = int(toks[t, b])
+                        reason = ("eos" if (self.eos_id is not None
+                                            and nxt == self.eos_id) else
+                                  ("max_tokens"
+                                   if len(req.out_tokens) >= req.max_new_tokens
+                                   else "out_of_cache"))
+                        self._retire(req, now, reason)
+                        self.slot_req[b] = None
+            for b in quarantine:
+                self._quarantine(b, now)
+            return int(self.live.sum())
 
     # ------------------------------------------------------------------
     def tick(self) -> bool:

@@ -371,6 +371,67 @@ def test_server_tracing_disabled_by_default(smollm):
     assert srv.stats()["decoded_tokens"] == 1
 
 
+# the persistent path's spans, as the server opens them
+STEP_BLOCK_SPANS = ("begin_tick", "admit", "prefill_oneshot", "splice",
+                    "first_token", "block_prep", "decode_block",
+                    "device_sync", "post_block")
+
+
+def _profiled_spans(smollm, log_dir, trace: bool) -> list[dict]:
+    """Serve two requests on a persistent server under a JAX profiler
+    session; the ``repro.*`` host events of the resulting ``.xplane.pb``."""
+    from jax.profiler import ProfileData
+
+    cfg, params = smollm
+    srv = DecodeServer(cfg, params, num_slots=2, max_seq=32, persistent=True,
+                       block_k=2, obs=obs_lib.Observability(trace=trace))
+    for uid in (0, 1):
+        srv.submit(Request(uid=uid, prompt=_prompt(3, seed=uid),
+                           max_new_tokens=4))
+    jax.profiler.start_trace(str(log_dir))
+    try:
+        srv.run_until_drained()
+    finally:
+        jax.profiler.stop_trace()
+    assert srv.stats()["decoded_tokens"] == 6
+    (path,) = log_dir.glob("plugins/profile/*/*.xplane.pb")
+    out = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            out += [{"plane": plane.name, "line": line.name,
+                     "name": e.name[len("repro."):], "start": e.start_ns,
+                     "end": e.start_ns + e.duration_ns, "args": dict(e.stats)}
+                    for e in line.events if e.name.startswith("repro.")]
+    return out
+
+
+def _inside(inner: dict, outer: dict) -> bool:
+    return (inner["line"] == outer["line"] and outer["start"] <= inner["start"]
+            and inner["end"] <= outer["end"])
+
+
+def test_enabled_spans_reach_the_profiler_trace(smollm, tmp_path):
+    evs = _profiled_spans(smollm, tmp_path, trace=True)
+    assert {e["name"] for e in evs} >= set(STEP_BLOCK_SPANS)
+    assert all(e["plane"].startswith("/host:") for e in evs)
+    by = {n: [e for e in evs if e["name"] == n] for n in STEP_BLOCK_SPANS}
+    for name in ("prefill_oneshot", "splice", "first_token"):
+        for e in by[name]:
+            assert any(_inside(e, a) for a in by["admit"]), name
+            assert any(_inside(e, t) for t in by["begin_tick"]), name
+    for e in by["device_sync"]:
+        assert any(_inside(e, d) for d in by["decode_block"])
+    # the spans of one request share its uid: one admission each
+    for name in ("prefill_oneshot", "splice", "first_token"):
+        assert sorted(e["args"]["uid"] for e in by[name]) == [0, 1], name
+    assert all(e["args"]["k"] == 2 for e in by["decode_block"])
+    assert sum(e["args"]["emitted"] for e in by["post_block"]) == 6
+
+
+def test_disabled_tracer_writes_no_profiler_spans(smollm, tmp_path):
+    assert _profiled_spans(smollm, tmp_path, trace=False) == []
+
+
 # ---------------------------------------------------------------------------
 # perf-suite regression gate (satellite: p95 gate for serve_mixed_*)
 # ---------------------------------------------------------------------------

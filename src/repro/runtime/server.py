@@ -15,9 +15,10 @@ Two decode drivers share the slot machinery:
   applied to serving): a jitted ``lax.scan`` over ``block_k`` decode steps
   that samples *on device* (batched argmax / ``jax.random.categorical`` with
   per-slot temperature), tracks per-slot live masks and EOS / max-token /
-  out-of-cache stopping on device, and returns only the K×B token block plus
-  updated carries.  One host sync per K tokens instead of per token — the
-  hot path is dispatch-bound, not sync-bound.  The cache carry layout is
+  out-of-cache stopping on device, and returns the K×B token block, its
+  flags and the updated carries packed into one array.  One upload and one
+  fetch per K tokens instead of a sync per token — the hot path is
+  dispatch-bound, not sync-bound.  The cache carry layout is
   exactly the ``splice_cache`` layout, so admission between blocks is
   unchanged.
 
@@ -114,6 +115,46 @@ def splice_cache(caches: PyTree, prefill_caches: PyTree, b: int, plen: int,  # n
         return dst
 
     return jax.tree_util.tree_map_with_path(one, caches, prefill_caches)
+
+
+# A decode block crosses the host↔device boundary as one int32 array each
+# way, slot axis last (so it shards like the slots).  In, [5, B]: cur, pos,
+# live (0/1), remaining, temps (float32 bits).  Out, [4K+3, B]: toks,
+# emitted, done_now, finite ([K, B] each, flags 0/1), then cur, pos, live.
+
+def pack_block_inputs(cur: np.ndarray, pos: np.ndarray, live: np.ndarray,
+                      remaining: np.ndarray, temps: np.ndarray) -> np.ndarray:
+    """Host side: the block's five per-slot inputs as one [5, B] int32."""
+    return np.stack([cur, pos, live, remaining,
+                     temps.astype(np.float32).view(np.int32)]
+                    ).astype(np.int32, copy=False)
+
+
+def unpack_block_inputs(packed: jax.Array):
+    """Device side of :func:`pack_block_inputs`; temps come back bit-exact."""
+    cur, pos, live, remaining, temps = packed
+    return (cur, pos, live != 0, remaining,
+            jax.lax.bitcast_convert_type(temps, jnp.float32))
+
+
+def pack_block_outputs(toks, emitted, done_now, finite, cur, pos,
+                       live) -> jax.Array:
+    """Device side: the [K, B] tick outputs and the [B] carry vectors as one
+    [4K+3, B] int32."""
+    i32 = lambda a: a.astype(jnp.int32)
+    return jnp.concatenate([i32(toks), i32(emitted), i32(done_now),
+                            i32(finite), i32(cur)[None], i32(pos)[None],
+                            i32(live)[None]])
+
+
+def unpack_block_outputs(packed: np.ndarray, k: int):
+    """Host side of :func:`pack_block_outputs`: (toks, emitted, done_now,
+    finite, cur, pos, live).  Every array but ``toks`` is a fresh writable
+    one: the quarantine pass masks ``emitted``/``done_now``, and ``_admit``
+    writes the ``cur``/``pos``/``live`` mirrors."""
+    flag = lambda i: packed[i * k:(i + 1) * k] != 0
+    return (packed[:k], flag(1), flag(2), flag(3), packed[4 * k].copy(),
+            packed[4 * k + 1].copy(), packed[4 * k + 2] != 0)
 
 
 @dataclasses.dataclass
@@ -275,6 +316,10 @@ class DecodeServer:
         m = self.obs.metrics
         self._m_syncs = m.counter("decode_syncs",
                                   "host round-trips in the decode phase")
+        self._m_d2h = m.counter("block_transfers",
+                                "decode-block boundary transfers", dir="d2h")
+        self._m_h2d = m.counter("block_transfers",
+                                "decode-block boundary transfers", dir="h2d")
         self._m_tokens = m.counter("decoded_tokens", "tokens generated")
         # prefill-phase telemetry: per-tick boundedness + cache savings
         self._m_prompt_steps = m.counter("prompt_steps_computed",
@@ -1013,7 +1058,8 @@ class DecodeServer:
         """Build the jitted K-step inner loop.  The carry is exactly the
         server's device state — (caches, cur_tokens, pos, live, remaining,
         key) — so a block is semantically K applications of ``step()`` with
-        sampling and retirement decided on device."""
+        sampling and retirement decided on device.  The server dispatches it
+        through :meth:`_make_packed_block_fn`."""
         cfg, S = self.cfg, self.S
         eos = np.int32(-1 if self.eos_id is None else self.eos_id)
 
@@ -1051,12 +1097,31 @@ class DecodeServer:
 
         return jax.jit(block)
 
+    def _make_packed_block_fn(self, k: int) -> Callable:
+        """The block ``step_block`` dispatches: the K-step scan of
+        :meth:`_make_block_fn` behind one packed int32 array each way
+        (:func:`pack_block_inputs`, :func:`pack_block_outputs`), so a block
+        costs one upload and one fetch.  Named ``block`` like the scan it
+        wraps: profilers show the decode program as ``jit_block``."""
+        scan = self._make_block_fn(k)
+
+        def block(params, caches, packed, key):
+            cur, pos, live, remaining, temps = unpack_block_inputs(packed)
+            carry, outs = scan(params, caches, cur, pos, live, remaining,
+                               temps, key)
+            caches, cur, pos, live, _, key = carry
+            return caches, key, pack_block_outputs(*outs, cur, pos, live)
+
+        return jax.jit(block)
+
     def step_block(self) -> int:
         """K decode ticks in ONE device dispatch; returns #live after.
 
-        Host work per block: unpack the [K, B] token block, append to the
-        per-request transcripts, retire finished requests.  Exactly one
-        host↔device sync for the whole block.
+        Host work per block: pack the per-slot inputs into one [5, B] array
+        and upload it, fetch the block's one [4K+3, B] output (the K×B token
+        block, its emitted/done/finite flags and the carry vectors; this
+        fetch is the block's only host↔device sync), append to the
+        per-request transcripts, retire finished requests.
 
         Timestamps (first_token_at / done_at) are stamped at the block
         boundary — the host cannot observe inner ticks without the very sync
@@ -1078,37 +1143,34 @@ class DecodeServer:
             k = self.block_k
             fn = self._block_fns.get(k)
             if fn is None:
-                fn = self._block_fns[k] = self._make_block_fn(k)
+                fn = self._block_fns[k] = self._make_packed_block_fn(k)
             temps = np.array(
                 [r.temperature if r is not None else 0.0 for r in self.slot_req],
                 np.float32)
             remaining = np.array(
                 [r.max_new_tokens - len(r.out_tokens) if r is not None else 0
                  for r in self.slot_req], np.int32)
+            packed_in = pack_block_inputs(self.cur_tokens, self.pos, self.live,
+                                          remaining, temps)
         with self._tr.span("decode_block", cat="decode",
                            args={"live": live_n, "k": k}):
             try:
                 if self._fire("decode.dispatch") is not None:
                     raise TransientFault("injected decode.dispatch fault")
-                carry, (toks, emitted, done_now, finite) = fn(
-                    self.params, self.caches, jnp.asarray(self.cur_tokens),
-                    jnp.asarray(self.pos), jnp.asarray(self.live),
-                    jnp.asarray(remaining), jnp.asarray(temps), self.key,
-                )
+                packed_in = jnp.asarray(packed_in)      # the one upload
+                self._m_h2d.inc()
+                self.caches, self.key, packed_out = fn(
+                    self.params, self.caches, packed_in, self.key)
             except TransientFault:
                 self._m_disp_retries.inc()
                 time.sleep(0.001)
                 return int(self.live.sum())
-            self.caches, cur, pos, live, _, self.key = carry
-            # ONE sync: the K×B block (plus the small carry vectors) to host.
+            # the block's one sync: its packed outputs to the host
             with self._tr.span("device_sync", cat="sync"):
-                toks = np.asarray(toks)
-                emitted = np.array(emitted)      # writable: the quarantine
-                done_now = np.array(done_now)    # pass masks bad ticks
-                finite = np.asarray(finite)
-                self.cur_tokens = np.array(cur)   # np.array copies: the host
-                self.pos = np.array(pos)          # mirrors stay writable for
-                self.live = np.array(live)        # _admit()
+                packed_out = np.asarray(packed_out)
+            self._m_d2h.inc()
+            (toks, emitted, done_now, finite, self.cur_tokens, self.pos,
+             self.live) = unpack_block_outputs(packed_out, k)
         with self._tr.span("post_block", cat="decode",
                            args={"emitted": int(emitted.sum())}):
             self._m_syncs.inc()
@@ -1175,6 +1237,9 @@ class DecodeServer:
             "decode_syncs": self.decode_syncs,
             "decoded_tokens": self.decoded_tokens,
             "syncs_per_token": self.decode_syncs / toks,
+            # decode-block boundary transfers per block sync (1 and 1)
+            "d2h_per_block": self._m_d2h.value / max(self.decode_syncs, 1),
+            "h2d_per_block": self._m_h2d.value / max(self.decode_syncs, 1),
             "prefill": {
                 "prompt_steps_computed": self.prompt_steps_computed,
                 "chunks_run": self.prefill_chunks_run,

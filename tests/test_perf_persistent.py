@@ -139,6 +139,111 @@ def test_persistent_recurrent_arch(smollm):
 
 
 # ---------------------------------------------------------------------------
+# the block's host boundary: one packed array each way
+# ---------------------------------------------------------------------------
+
+_S = 48
+# (cur, pos, live, remaining, temps) over B=4 slots
+_BLOCK_INPUTS = {
+    "edge_temps": ([3, 7, 0, 11], [5, _S - 1, 0, 9], [1, 1, 0, 1],
+                   [4, 2, 0, -3], [0.0, 1e-6, 1.7, 0.0]),
+    "all_dead": ([0, 0, 0, 0], [0, 0, 0, 0], [0, 0, 0, 0],
+                 [0, -1, 0, 0], [0.0, 0.0, 0.0, 0.0]),
+    "all_live": ([1, 2, 3, 4], [_S - 1, 1, _S - 2, 7], [1, 1, 1, 1],
+                 [1, 8, 3, 100], [1.7, 1e-6, 0.0, 0.8]),
+}
+
+
+def _block_inputs(case):
+    cur, pos, live, remaining, temps = _BLOCK_INPUTS[case]
+    return (np.array(cur, np.int32), np.array(pos, np.int32),
+            np.array(live, bool), np.array(remaining, np.int32),
+            np.array(temps, np.float32))
+
+
+@pytest.mark.parametrize("case", sorted(_BLOCK_INPUTS))
+def test_block_inputs_round_trip(case):
+    """Host pack → device unpack gives every input back, temps bit-exact."""
+    from repro.runtime.server import pack_block_inputs, unpack_block_inputs
+
+    want = _block_inputs(case)
+    packed = pack_block_inputs(*want)
+    assert packed.dtype == np.int32 and packed.shape == (5, 4)
+    got = jax.jit(unpack_block_inputs)(jnp.asarray(packed))
+    for w, g in zip(want, got):
+        g = np.asarray(g)
+        assert g.dtype == w.dtype
+        np.testing.assert_array_equal(g.view(np.uint8), w.view(np.uint8))
+
+
+def test_block_outputs_round_trip():
+    """Device pack → host unpack, with a slot that retires mid-block: every
+    output comes back, and the arrays the host writes are writable."""
+    from repro.runtime.server import pack_block_outputs, unpack_block_outputs
+
+    K, B = 4, 3
+    toks = np.arange(K * B, dtype=np.int32).reshape(K, B) + 30_000
+    emitted = np.ones((K, B), bool)
+    emitted[2:, 1] = False               # slot 1 retires at tick 1
+    emitted[:, 2] = False                # slot 2 is dead all block
+    done_now = np.zeros((K, B), bool)
+    done_now[1, 1] = True
+    finite = np.ones((K, B), bool)
+    finite[3, 0] = False
+    cur = np.array([5, 7, 0], np.int32)
+    pos = np.array([_S - 1, 9, 0], np.int32)
+    live = np.array([True, False, False])
+    want = (toks, emitted, done_now, finite, cur, pos, live)
+    packed = np.asarray(jax.jit(pack_block_outputs)(*map(jnp.asarray, want)))
+    assert packed.dtype == np.int32 and packed.shape == (4 * K + 3, B)
+    got = unpack_block_outputs(packed, K)
+    for w, g in zip(want, got):
+        assert g.dtype == w.dtype
+        np.testing.assert_array_equal(g, w)
+    for g in got[1:]:
+        assert g.flags.writeable
+
+
+@pytest.mark.parametrize("case", sorted(_BLOCK_INPUTS))
+def test_packed_block_matches_scan(smollm, case):
+    """The dispatched block is the K-step scan, bit for bit, behind the
+    packed boundary: tokens, flags, carries, caches and key."""
+    from repro.runtime.server import (pack_block_inputs,
+                                      unpack_block_outputs)
+
+    cfg, params = smollm
+    K = 4
+    srv = DecodeServer(cfg, params, num_slots=4, max_seq=_S, block_k=K,
+                       persistent=True)
+    ins = _block_inputs(case)
+    carry, outs = srv._make_block_fn(K)(params, srv.caches,
+                                        *map(jnp.asarray, ins), srv.key)
+    caches, key, packed = srv._make_packed_block_fn(K)(
+        params, srv.caches, jnp.asarray(pack_block_inputs(*ins)), srv.key)
+    got = unpack_block_outputs(np.asarray(packed), K)
+    want = (*outs, carry[1], carry[2], carry[3])
+    for w, g in zip(want, got):
+        np.testing.assert_array_equal(g, np.asarray(w))
+    np.testing.assert_array_equal(np.asarray(key), np.asarray(carry[5]))
+    for a, b in zip(jax.tree.leaves(caches), jax.tree.leaves(carry[0])):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_one_transfer_each_way_per_block(smollm):
+    """A persistent server crosses the block boundary once each way."""
+    cfg, params = smollm
+    reqs = _requests(cfg.vocab, n=5, max_new=11, seed=4)
+    _, srv = _drain(cfg, params, persistent=True, block_k=4, reqs=reqs,
+                    slots=3)
+    s = srv.stats()
+    assert s["decode_syncs"] > 2
+    assert s["d2h_per_block"] == 1 and s["h2d_per_block"] == 1
+    m = srv.obs.metrics
+    assert m.value("block_transfers", dir="d2h") == s["decode_syncs"]
+    assert m.value("block_transfers", dir="h2d") == s["decode_syncs"]
+
+
+# ---------------------------------------------------------------------------
 # ragged shapes: pad + mask instead of degrade/crash (satellite 1)
 # ---------------------------------------------------------------------------
 

@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from .config import ModelConfig
-from .layers import apply_rope, dense_init, rmsnorm, rmsnorm_params
+from .layers import apply_rope, dense_init, rmsnorm, rmsnorm_params, yarn_freqs, yarn_get_mscale
 
 PyTree = Any
 
@@ -193,13 +193,34 @@ def gqa_decode(p, cfg: ModelConfig, x, cache: PyTree, pos, *, window: int = 0):
 # MLA (DeepSeek-V2): compressed-latent cache; naive prefill + absorbed decode
 # ---------------------------------------------------------------------------
 
+def _mla_rope(cfg: ModelConfig, x, positions):
+    """MLA's rotary embedding: plain RoPE, or YaRN's frequencies with its
+    cos/sin scale where ``rope_yarn_factor`` is set."""
+    if not cfg.rope_yarn_factor:
+        return apply_rope(x, positions, cfg.rope_theta)
+    f = cfg.rope_yarn_factor
+    freqs = yarn_freqs(cfg.qk_rope_head_dim, cfg.rope_theta, f, cfg.rope_yarn_orig_max_pos,
+                       cfg.rope_yarn_beta_fast, cfg.rope_yarn_beta_slow)
+    mscale = yarn_get_mscale(f, cfg.rope_yarn_mscale) / yarn_get_mscale(
+        f, cfg.rope_yarn_mscale_all_dim)
+    return apply_rope(x, positions, cfg.rope_theta, freqs=freqs, mscale=mscale)
+
+
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """(dn + dr)^-1/2, times YaRN's mscale squared where it is set."""
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    if cfg.rope_yarn_factor and cfg.rope_yarn_mscale_all_dim:
+        scale *= yarn_get_mscale(cfg.rope_yarn_factor, cfg.rope_yarn_mscale_all_dim) ** 2
+    return scale
+
+
 def _mla_q(p, cfg: ModelConfig, x, positions):
     B, S, _ = x.shape
     H = cfg.n_heads
     dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     q = (x @ p["wq"]).reshape(B, S, H, dn + dr)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = _mla_rope(cfg, q_rope, positions)
     return q_nope, q_rope
 
 
@@ -213,11 +234,11 @@ def mla_prefill(p, cfg: ModelConfig, x, positions=None):
     q_nope, q_rope = _mla_q(p, cfg, x, positions)
 
     c_kv = rmsnorm(p["kv_norm"], x @ p["w_dkv"], cfg.norm_eps)        # [B,S,r]
-    k_rope = apply_rope((x @ p["w_krope"]).reshape(B, S, 1, dr), positions, cfg.rope_theta)
+    k_rope = _mla_rope(cfg, (x @ p["w_krope"]).reshape(B, S, 1, dr), positions)
     k_nope = (c_kv @ p["w_uk"]).reshape(B, S, H, dn)
     v = (c_kv @ p["w_uv"]).reshape(B, S, H, dv)
 
-    scale = (dn + dr) ** -0.5
+    scale = mla_softmax_scale(cfg)
     s_nope = jnp.einsum("bshd,bthd->bhst", q_nope.astype(jnp.float32), k_nope.astype(jnp.float32))
     s_rope = jnp.einsum("bshd,bthd->bhst", q_rope.astype(jnp.float32),
                         jnp.broadcast_to(k_rope, (B, S, 1, dr)).astype(jnp.float32))
@@ -229,11 +250,14 @@ def mla_prefill(p, cfg: ModelConfig, x, positions=None):
     return out.reshape(B, S, -1) @ p["wo"], (c_kv, k_rope[:, :, 0, :])
 
 
-def mla_decode(p, cfg: ModelConfig, x, cache: PyTree, pos):
+def mla_decode(p, cfg: ModelConfig, x, cache: PyTree, pos, layer=None):
     """Absorbed decode (the MLA serving trick): attend in the latent space.
 
     cache = {"c_kv": [B, S_max, r], "k_rope": [B, S_max, dr]} — 576 floats
     per token per layer instead of 2·H·hd = 4096: the low-rank *state*.
+    With ``layer`` the leaves are the whole stack [G, B, S_max, ...] and
+    this layer's rows are written into it in place, then read back: the
+    stack is never copied.
     W_UK is absorbed into the query, W_UV into the output:
         score = (q_nope Wuk_h) · c_kv + q_rope · k_rope
         out_h = (probs · c_kv) Wuv_h
@@ -246,16 +270,20 @@ def mla_decode(p, cfg: ModelConfig, x, cache: PyTree, pos):
     q_nope, q_rope = _mla_q(p, cfg, x, qpos)
 
     c_new = rmsnorm(p["kv_norm"], x @ p["w_dkv"], cfg.norm_eps)
-    kr_new = apply_rope((x @ p["w_krope"]).reshape(B, S, 1, dr), qpos, cfg.rope_theta)[:, :, 0]
+    kr_new = _mla_rope(cfg, (x @ p["w_krope"]).reshape(B, S, 1, dr), qpos)[:, :, 0]
     bidx = jnp.arange(B)
-    c_kv = cache["c_kv"].at[bidx[:, None], qpos].set(c_new.astype(cache["c_kv"].dtype))
-    k_rope = cache["k_rope"].at[bidx[:, None], qpos].set(kr_new.astype(cache["k_rope"].dtype))
+    at = (bidx[:, None], qpos) if layer is None else (layer, bidx[:, None], qpos)
+    new_cache = {"c_kv": cache["c_kv"].at[at].set(c_new.astype(cache["c_kv"].dtype)),
+                 "k_rope": cache["k_rope"].at[at].set(kr_new.astype(cache["k_rope"].dtype))}
+    c_kv, k_rope = new_cache["c_kv"], new_cache["k_rope"]
+    if layer is not None:
+        c_kv, k_rope = c_kv[layer], k_rope[layer]
 
     w_uk = p["w_uk"].reshape(r, H, dn)
     q_lat = jnp.einsum("bshd,rhd->bshr", q_nope.astype(jnp.float32), w_uk.astype(jnp.float32))
     s_lat = jnp.einsum("bshr,btr->bhst", q_lat, c_kv.astype(jnp.float32))
     s_rope = jnp.einsum("bshd,btd->bhst", q_rope.astype(jnp.float32), k_rope.astype(jnp.float32))
-    scores = (s_lat + s_rope) * ((dn + dr) ** -0.5)
+    scores = (s_lat + s_rope) * mla_softmax_scale(cfg)
     T = c_kv.shape[1]
     mask = jnp.arange(T)[None, None, None, :] <= qpos[:, None, :, None]
     scores = jnp.where(mask, scores, NEG_INF)
@@ -263,7 +291,7 @@ def mla_decode(p, cfg: ModelConfig, x, cache: PyTree, pos):
     o_lat = jnp.einsum("bhst,btr->bshr", probs, c_kv.astype(jnp.float32))
     w_uv = p["w_uv"].reshape(r, H, dv)
     out = jnp.einsum("bshr,rhd->bshd", o_lat, w_uv.astype(jnp.float32)).astype(x.dtype)
-    return out.reshape(B, S, -1) @ p["wo"], {"c_kv": c_kv, "k_rope": k_rope}
+    return out.reshape(B, S, -1) @ p["wo"], new_cache
 
 
 # ---------------------------------------------------------------------------

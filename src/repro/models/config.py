@@ -40,6 +40,13 @@ class ModelConfig:
     qk_norm: bool = False
     rope_theta: float = 10_000.0
     rope_theta_global: float = 0.0   # gemma3: global layers use a larger base
+    # YaRN context extension (deepseek-v2): factor 0 = plain RoPE
+    rope_yarn_factor: float = 0.0
+    rope_yarn_orig_max_pos: int = 0
+    rope_yarn_beta_fast: float = 32.0
+    rope_yarn_beta_slow: float = 1.0
+    rope_yarn_mscale: float = 1.0
+    rope_yarn_mscale_all_dim: float = 0.0
     partial_rotary: float = 1.0      # fraction of head_dim carrying RoPE
     sliding_window: int = 0          # >0 enables local attention windows
     global_every: int = 0            # gemma3: 1 global layer per N (pattern)
@@ -54,6 +61,10 @@ class ModelConfig:
     top_k: int = 0
     n_shared_experts: int = 0
     d_ff_expert: int = 0
+    norm_topk_prob: bool = True      # renormalise the top-k router weights
+    # experts 0..n_experts_held-1 of every MoE layer live here (0 = all): the
+    # chip's share under expert parallelism; the router still spans n_experts
+    n_experts_held: int = 0
     capacity_factor: float = 1.25
     router_aux_coef: float = 0.01
     moe_group_size: int = 0          # dispatch group tokens (0 = 2048 default);
@@ -100,6 +111,8 @@ class ModelConfig:
     # batch sharded over ALL axes (pod×data×model). Right regime for models
     # whose weights fit one chip (smollm); a §Perf hillclimb knob.
     pure_dp: bool = False
+    # dense ("attn") layers BEFORE the scan (deepseek's first_k_dense_replace)
+    first_dense_layers: int = 0
     # blocks appended AFTER the scan when n_layers % period != 0
     # (gemma3: 62 = 6*10 + 2 local; zamba2: 38 = 6*6 + 2 mamba2):
     tail_pattern: tuple = ()
@@ -130,6 +143,10 @@ class ModelConfig:
         return jnp.dtype(self.param_dtype)
 
     @property
+    def experts_held(self) -> int:
+        return self.n_experts_held or self.n_experts
+
+    @property
     def layer_pattern(self) -> tuple[str, ...]:
         """The repeating block pattern (the scan body's inner structure).
 
@@ -155,11 +172,12 @@ class ModelConfig:
     @property
     def n_groups(self) -> int:
         period = len(self.layer_pattern)
-        body = self.n_layers - len(self.tail_pattern)
+        body = self.n_layers - len(self.tail_pattern) - self.first_dense_layers
         if body % period:
             raise ValueError(
                 f"{self.name}: n_layers={self.n_layers} minus tail "
-                f"{len(self.tail_pattern)} not divisible by pattern period "
+                f"{len(self.tail_pattern)} and {self.first_dense_layers} leading "
+                f"dense layers not divisible by pattern period "
                 f"{period} ({self.layer_pattern})"
             )
         return body // period
@@ -173,13 +191,14 @@ class ModelConfig:
         bpe = 2
         pat = self.layer_pattern
         n_groups = self.n_groups
+        if self.use_mla:
+            attn = batch * seq * (self.kv_lora_rank + self.qk_rope_head_dim) * bpe
+        else:
+            attn = 2 * batch * seq * self.n_kv_heads * self.head_dim * bpe
         total = 0
         for kind in pat:
             if kind in ("attn", "moe", "cross"):
-                if self.use_mla:
-                    total += batch * seq * (self.kv_lora_rank + self.qk_rope_head_dim) * bpe
-                else:
-                    total += 2 * batch * seq * self.n_kv_heads * self.head_dim * bpe
+                total += attn
             elif kind == "attn_local":
                 s = min(seq, self.sliding_window)
                 total += 2 * batch * s * self.n_kv_heads * self.head_dim * bpe
@@ -197,7 +216,7 @@ class ModelConfig:
                         self.n_mamba_heads * self.mamba_headdim * self.ssm_state
                         + (self.d_inner + 2 * self.ssm_state) * (self.d_conv - 1)
                     ) * 4
-        return total * n_groups
+        return total * n_groups + self.first_dense_layers * attn
 
 
 @dataclasses.dataclass(frozen=True)

@@ -8,6 +8,7 @@ in `attention.py` / `ssm.py`.
 
 from __future__ import annotations
 
+import math
 from typing import Any
 
 import jax
@@ -69,15 +70,43 @@ def rope_freqs(head_dim: int, theta: float, partial: float = 1.0) -> jnp.ndarray
     return 1.0 / (theta ** (jnp.arange(0, rot, 2, dtype=jnp.float32) / rot))
 
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float, partial: float = 1.0):
+def yarn_get_mscale(scale: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature for a context stretched ``scale`` times
+    (DeepSeek-V2's ``yarn_get_mscale``)."""
+    return 0.1 * mscale * math.log(scale) + 1.0 if scale > 1 else 1.0
+
+
+def yarn_freqs(dim: int, theta: float, factor: float, orig_max_pos: int,
+               beta_fast: float, beta_slow: float) -> jnp.ndarray:
+    """YaRN frequencies of the ``dim/2`` rotated pairs: pairs that turn more
+    than ``beta_fast`` times over the original context keep their frequency,
+    pairs that turn fewer than ``beta_slow`` times are slowed ``factor``-fold,
+    and a linear ramp between the two correction dims blends the rest."""
+    extra = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    inter = extra / factor
+
+    def correction_dim(rotations):
+        return dim * math.log(orig_max_pos / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return jnp.asarray(inter * ramp + extra * (1.0 - ramp), jnp.float32)
+
+
+def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float, partial: float = 1.0,
+               *, freqs: jnp.ndarray | None = None, mscale: float = 1.0):
     """x: [..., S, H, hd]; positions: [..., S] (int).  Rotates the first
-    ``partial * hd`` channels, passes the rest through (phi4-style)."""
+    ``partial * hd`` channels, passes the rest through (phi4-style).
+    ``freqs`` replaces the plain RoPE frequencies (YaRN), and ``mscale``
+    scales cos and sin."""
     hd = x.shape[-1]
-    freqs = rope_freqs(hd, theta, partial)         # [rot/2]
+    if freqs is None:
+        freqs = rope_freqs(hd, theta, partial)     # [rot/2]
     rot = freqs.shape[0] * 2
     angles = positions[..., :, None].astype(jnp.float32) * freqs  # [..., S, rot/2]
-    cos = jnp.cos(angles)[..., :, None, :]          # [..., S, 1, rot/2]
-    sin = jnp.sin(angles)[..., :, None, :]
+    cos = (jnp.cos(angles) * mscale)[..., :, None, :]   # [..., S, 1, rot/2]
+    sin = (jnp.sin(angles) * mscale)[..., :, None, :]
     x_rot, x_pass = x[..., :rot], x[..., rot:]
     x1, x2 = x_rot[..., ::2], x_rot[..., 1::2]
     y1 = x1 * cos - x2 * sin

@@ -22,6 +22,7 @@ from .transformer import (
     apply_block,
     group_params,
     init_cache,
+    lead_params,
     shared_block_params,
     tail_params,
 )
@@ -49,6 +50,10 @@ def init_params(cfg: ModelConfig, key) -> PyTree:
     else:
         params["embed"] = embedding_params(ks[0], cfg.vocab, cfg.d_model, cfg.p_dtype)
 
+    lead = lead_params(jax.random.fold_in(key, 5), cfg)
+    if lead is not None:
+        params["lead"] = lead
+
     gkeys = jax.random.split(ks[1], cfg.n_groups)
     params["groups"] = jax.vmap(lambda k: group_params(k, cfg))(gkeys)
 
@@ -74,36 +79,88 @@ def param_count(params: PyTree) -> int:
 # group scan
 # ---------------------------------------------------------------------------
 
-def _apply_groups(params, cfg: ModelConfig, x, *, memory, caches, pos, mode):
-    pattern = cfg.layer_pattern
-    shared = params.get("shared")
+def _scan_stack(params_stack, caches_stack, pattern, shared, cfg: ModelConfig, h, aux, *,
+                memory, pos, mode):
+    """Scan one group body (a period of ``pattern``) over stacked params and
+    caches; returns (h, aux, stacked new caches).
+
+    Caches go in as xs and come out as ys, except MLA latents in the serving
+    modes: those ride the carry whole and each layer writes its rows in
+    place, so a [G, B, S_max, r] stack is never held twice.  MLA alone takes
+    this path because its latent stack is the one cache a served
+    configuration holds at a size where the second copy does not fit
+    (deepseek-v2-lite's 6 GB stack on a 16 GB chip); ``mla_decode`` reads
+    and writes its layer of the stack by index.  KV caches and recurrent
+    states stay xs/ys: their updates take one layer's cache, and the
+    served recurrent states are a few MB.
+
+    Likewise the held experts' weights stay whole in the serving modes and
+    the expert kernel reads its layer of them, rather than a per-layer
+    copy."""
+    carried, experts = {}, {}
+    if caches_stack is not None and cfg.use_mla and mode in ("decode", "chunk"):
+        carried = {n: c for n, c in caches_stack.items()
+                   if isinstance(c, dict) and "c_kv" in c}
+        caches_stack = {n: c for n, c in caches_stack.items() if n not in carried}
+    if mode != "train":
+        names = ("w_in", "w_gate", "w_out")
+        experts = {n: {w: p["moe"][w] for w in names}
+                   for n, p in params_stack.items() if "moe" in p}
+        params_stack = {n: dict(p, moe={w: v for w, v in p["moe"].items() if w not in names})
+                        if n in experts else p for n, p in params_stack.items()}
 
     def group_body(carry, xs):
-        h, aux = carry
+        h, aux, carried, layer = carry
         h = constrain_activation(h)  # pin batch-over-DP each group (no-op on 1 dev)
         p_grp, cache_grp = xs
         new_caches = {}
+        carried = dict(carried)
         for i, kind in enumerate(pattern):
             name = f"b{i}_{kind}"
+            if name in experts:
+                p_grp = dict(p_grp, **{name: dict(p_grp[name], moe={
+                    **p_grp[name]["moe"], **experts[name]})})
+            if name in carried:
+                h, carried[name], aux_i = apply_block(
+                    p_grp[name], shared, cfg, kind, h,
+                    memory=memory, cache=carried[name], pos=pos, mode=mode, layer=layer,
+                )
+                aux = aux + aux_i
+                continue
             c_in = None if cache_grp is None else cache_grp.get(name)
             h, c_out, aux_i = apply_block(
                 p_grp[name], shared, cfg, kind, h,
                 memory=memory, cache=c_in, pos=pos, mode=mode,
+                layer=layer if name in experts else None,
             )
             aux = aux + aux_i
             new_caches[name] = c_out if c_out is not None else jnp.zeros((), jnp.float32)
-        return (h, aux), new_caches
+        return (h, aux, carried, layer + 1), new_caches
 
     body = group_body
     if cfg.remat and mode == "train":
         body = jax.checkpoint(group_body)
 
-    xs = (params["groups"], None if caches is None else caches["groups"])
-    (h, aux), out_group_caches = jax.lax.scan(
-        body, (x, jnp.zeros((), jnp.float32)), xs, unroll=cfg.scan_unroll
+    (h, aux, carried, _), out_caches = jax.lax.scan(
+        body, (h, aux, carried, jnp.zeros((), jnp.int32)), (params_stack, caches_stack),
+        unroll=cfg.scan_unroll
     )
+    return h, aux, {**out_caches, **carried}
 
-    out_caches = {"groups": out_group_caches}
+
+def _apply_groups(params, cfg: ModelConfig, x, *, memory, caches, pos, mode):
+    shared = params.get("shared")
+    aux = jnp.zeros((), jnp.float32)
+    out_caches = {}
+    h = x
+    if cfg.first_dense_layers:
+        h, aux, out_caches["lead"] = _scan_stack(
+            params["lead"], None if caches is None else caches["lead"], ("attn",),
+            shared, cfg, h, aux, memory=memory, pos=pos, mode=mode)
+    h, aux, out_caches["groups"] = _scan_stack(
+        params["groups"], None if caches is None else caches["groups"], cfg.layer_pattern,
+        shared, cfg, h, aux, memory=memory, pos=pos, mode=mode)
+
     if cfg.tail_pattern:
         tail_in = None if caches is None else caches.get("tail")
         tail_out = {}

@@ -7,7 +7,7 @@ architecture's block pattern (``ModelConfig.layer_pattern``):
     dense            -> ("attn",)
     gemma3           -> ("attn_local",)*5 + ("attn",)         # 5:1 local:global
     llama-vision     -> ("attn",)*4 + ("cross",)
-    moe              -> ("moe",)
+    moe              -> ("moe",)        (deepseek: after its dense first_dense_layers)
     falcon-mamba     -> ("mamba1",)
     zamba2           -> ("mamba2",)*k + ("shared_attn",)       # shared weights!
 
@@ -93,6 +93,16 @@ def group_params(key, cfg: ModelConfig) -> PyTree:
     return {f"b{i}_{kind}": _block_params(keys[i], cfg, kind) for i, kind in enumerate(pat)}
 
 
+def lead_params(key, cfg: ModelConfig) -> PyTree | None:
+    """The ``first_dense_layers`` dense blocks before the scanned groups,
+    stacked like a group of one ``attn`` block (deepseek's
+    ``first_k_dense_replace``)."""
+    if not cfg.first_dense_layers:
+        return None
+    keys = jax.random.split(key, cfg.first_dense_layers)
+    return jax.vmap(lambda k: {"b0_attn": _block_params(k, cfg, "attn")})(keys)
+
+
 def shared_block_params(key, cfg: ModelConfig) -> PyTree | None:
     """Zamba2's single shared transformer block (attention + MLP)."""
     if "shared_attn" not in cfg.layer_pattern:
@@ -142,9 +152,18 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int) -> PyTree:
     """Decode cache: {"groups": stacked-over-G, "tail": per-block} — the
     serving state vector."""
     pat = cfg.layer_pattern
-    one = {f"b{i}_{kind}": _block_cache(cfg, kind, batch, max_seq) for i, kind in enumerate(pat)}
-    G = cfg.n_groups
-    cache = {"groups": jax.tree.map(lambda leaf: jnp.broadcast_to(leaf, (G,) + leaf.shape).copy(), one)}
+
+    def stack(n, blocks):
+        # every state starts at zero: each stack is made whole, never as a
+        # copy of one layer's buffer (the stacks are the largest arrays a
+        # server holds, and a copy would hold them twice while it runs)
+        one = jax.eval_shape(lambda: {f"b{i}_{kind}": _block_cache(cfg, kind, batch, max_seq)
+                                      for i, kind in enumerate(blocks)})
+        return jax.tree.map(lambda s: jnp.zeros((n,) + s.shape, s.dtype), one)
+
+    cache = {"groups": stack(cfg.n_groups, pat)}
+    if cfg.first_dense_layers:
+        cache["lead"] = stack(cfg.first_dense_layers, ("attn",))
     if cfg.tail_pattern:
         cache["tail"] = {
             f"t{i}_{kind}": _block_cache(cfg, kind, batch, max_seq)
@@ -189,8 +208,13 @@ def apply_block(
     cache=None,
     pos=None,
     mode: str = "train",
+    layer=None,
 ):
     """One block, all kinds, all modes.  Returns (x, new_cache, aux_loss).
+
+    ``layer`` (serving modes): this block is the ``layer``-th of its stack;
+    an MLA decode or chunk ``cache`` is then the whole stack (see
+    ``attention.mla_decode``), and so are the held experts' weights.
 
     mode="chunk" is the *resumable prefill* step: S ≥ 1 tokens applied
     against an existing cache at offset ``pos`` — the same state-update map
@@ -210,11 +234,12 @@ def apply_block(
             acfg = dataclasses.replace(cfg, rope_theta=cfg.rope_theta_global)
         h = rmsnorm(p_blk["ln_attn"], x, cfg.norm_eps)
         if cfg.use_mla:
-            if decode:
-                a, cache = attn_lib.mla_decode(p_blk["attn"], acfg, h, cache, pos)
-            else:
-                a, kv = attn_lib.mla_prefill(p_blk["attn"], acfg, h)
-                cache = {"c_kv": kv[0], "k_rope": kv[1]} if mode == "prefill" else None
+            with jax.named_scope("mla"):
+                if decode:
+                    a, cache = attn_lib.mla_decode(p_blk["attn"], acfg, h, cache, pos, layer)
+                else:
+                    a, kv = attn_lib.mla_prefill(p_blk["attn"], acfg, h)
+                    cache = {"c_kv": kv[0], "k_rope": kv[1]} if mode == "prefill" else None
         else:
             if decode:
                 if kind == "attn_local":
@@ -229,10 +254,12 @@ def apply_block(
                 cache = {"k": kv[0], "v": kv[1]} if mode == "prefill" else None
         x = x + a
         h = rmsnorm(p_blk["ln_mlp"], x, cfg.norm_eps)
-        if kind == "moe":
+        if kind == "moe" and mode == "train":
             y, aux = moe_lib.moe_apply(
                 p_blk["moe"], cfg, h, group_size=cfg.moe_group_size or 2048
             )
+        elif kind == "moe":
+            y = moe_lib.held_experts_apply(p_blk["moe"], cfg, h, layer=layer)
         else:
             y = mlp_apply(p_blk["mlp"], h, cfg.mlp_act)
         return x + y, cache, aux

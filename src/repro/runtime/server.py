@@ -42,6 +42,7 @@ out of that:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Any, Callable
 
@@ -66,9 +67,11 @@ DEFAULT_BLOCK_K = 8
 _SEQ_LEAVES = ("k", "v", "c_kv", "k_rope")
 
 
-def splice_cache(caches: PyTree, prefill_caches: PyTree, b: int, plen: int,  # noqa: ARG001 — plen kept in the admission API; lengths derive from leaf shapes
+def splice_cache(caches: PyTree, prefill_caches: PyTree, b,
                  max_seq: int | None = None) -> PyTree:
     """Insert a B=1 prefill cache into batch slot ``b`` of the server cache.
+    ``b`` may be traced: the server runs this as one jitted program that
+    takes ``caches`` donated, so a splice updates the cache in place.
 
     Handles: full-length KV ([G,1,L,..] → [G,B,S_max,..] left-aligned), MLA
     latents, sliding-window ring buffers (last W positions placed at
@@ -115,6 +118,21 @@ def splice_cache(caches: PyTree, prefill_caches: PyTree, b: int, plen: int,  # n
         return dst
 
     return jax.tree_util.tree_map_with_path(one, caches, prefill_caches)
+
+
+def fill_slot(caches: PyTree, b, value, *, num_slots: int,
+              floating_only: bool) -> PyTree:
+    """Set batch row ``b`` of every cache leaf that has the slot axis (axis
+    1, of length ``num_slots``) to ``value``; ``floating_only`` leaves
+    integer leaves alone."""
+
+    def one(leaf):
+        if hasattr(leaf, "ndim") and leaf.ndim >= 2 and leaf.shape[1] == num_slots and (
+                not floating_only or jnp.issubdtype(leaf.dtype, jnp.floating)):
+            return leaf.at[:, b].set(jnp.asarray(value).astype(leaf.dtype))
+        return leaf
+
+    return jax.tree_util.tree_map(one, caches)
 
 
 # A decode block crosses the host↔device boundary as one int32 array each
@@ -298,9 +316,23 @@ class DecodeServer:
         self.cur_tokens = np.zeros(num_slots, np.int32)
         self.completed: list[Request] = []
         self.key = jax.random.PRNGKey(seed)
-        self._decode = jax.jit(
-            lambda p, t, c, pos: lm.decode_step(p, cfg, t, c, pos)
-        )
+        # The decode cache exists once: every program that updates it takes
+        # it donated (decode step, decode block, splice, slot fill), so an
+        # update is in place and the old handle is dead the moment it runs.
+        self._decode = self._on_mesh(jax.jit(
+            lambda p, t, c, pos: lm.decode_step(p, cfg, t, c, pos),
+            donate_argnums=2))
+
+        def splice(caches, src, b):
+            # looked up when traced, so a patched module attribute reaches it
+            return splice_cache(caches, src, b, max_seq)
+
+        self._splice_fn = jax.jit(splice, donate_argnums=0)
+        self._poison_fn = jax.jit(functools.partial(
+            fill_slot, num_slots=num_slots, floating_only=True), donate_argnums=0)
+        self._scrub_fn = jax.jit(functools.partial(
+            fill_slot, value=0, num_slots=num_slots, floating_only=False),
+            donate_argnums=0)
         self._prefill = self._on_mesh(
             jax.jit(lambda p, t: lm.prefill(p, cfg, t)))
         self._chunk_fns: dict[int, Callable] = {}       # chunk len -> jitted
@@ -550,27 +582,12 @@ class DecodeServer:
         leaves only) — the injected effect of the carry/splice fault points.
         Other slots' rows are untouched, so survivors stay bit-identical."""
         bad = float("nan") if mode == "nan" else float("inf")
-
-        def one(leaf):
-            if hasattr(leaf, "ndim") and leaf.ndim >= 2 \
-                    and leaf.shape[1] == self.B \
-                    and jnp.issubdtype(leaf.dtype, jnp.floating):
-                return leaf.at[:, b].set(bad)
-            return leaf
-
-        self.caches = jax.tree_util.tree_map(one, self.caches)
+        self.caches = self._poison_fn(self.caches, b, bad)
 
     def _scrub_slot(self, b: int) -> None:
         """Zero slot ``b``'s cache rows — quarantined state must never leak
         into the next request admitted to the slot."""
-
-        def one(leaf):
-            if hasattr(leaf, "ndim") and leaf.ndim >= 2 \
-                    and leaf.shape[1] == self.B:
-                return leaf.at[:, b].set(jnp.zeros((), leaf.dtype))
-            return leaf
-
-        self.caches = jax.tree_util.tree_map(one, self.caches)
+        self.caches = self._scrub_fn(self.caches, b)
 
     def _quarantine(self, b: int, now: float) -> None:
         """Retire slot ``b``'s request with ``error:nonfinite`` and pull the
@@ -734,13 +751,12 @@ class DecodeServer:
             self.pos[b] = len(req.prompt)
             self.cur_tokens[b] = first
 
-    def _splice(self, uid: int, b: int, prefill_caches: PyTree,
-                plen: int) -> None:
-        """Write a B=1 prompt state into slot ``b`` of the decode caches."""
+    def _splice(self, uid: int, b: int, prefill_caches: PyTree) -> None:
+        """Write a B=1 prompt state into slot ``b`` of the decode caches, in
+        place (one jitted program that takes the caches donated)."""
         with self._tr.span("splice", cat="admit", args={"uid": uid}):
-            self.caches = splice_cache(self.caches,
-                                       self._to_mesh(prefill_caches), b,
-                                       plen, self.S)
+            self.caches = self._splice_fn(self.caches,
+                                          self._to_mesh(prefill_caches), b)
 
     def _chunk_fn(self, c: int) -> Callable:
         fn = self._chunk_fns.get(c)
@@ -752,10 +768,12 @@ class DecodeServer:
         return fn
 
     def _on_mesh(self, fn: Callable) -> Callable:
-        """Trace and run a prefill program with the plan's mesh in context
-        when the state is physically sharded: Mosaic kernels inside it (the
-        generated stage) cannot be partitioned by GSPMD, and replicate
-        themselves over the context mesh instead."""
+        """Trace and run a model program (prefill, chunk, decode step,
+        decode block) with the plan's mesh in context when the state is
+        physically sharded: Mosaic kernels inside it cannot be partitioned
+        by GSPMD, and ``shard_map`` themselves over the context mesh instead
+        (the generated stage replicated, the held experts divided over
+        ``model``)."""
         if self._repl is None:
             return fn
         mesh = self.plan.mesh
@@ -803,8 +821,7 @@ class DecodeServer:
         plan the fresh buffer is lifted first: stored checkpoints are mesh-
         committed, and eager splice ops reject mixed device sets."""
         fresh = self._to_mesh(lm.init_cache(self.cfg, 1, self.S))
-        return splice_cache(fresh, self._to_mesh(entry.caches), 0,
-                            entry.length, self.S)
+        return self._splice_fn(fresh, self._to_mesh(entry.caches), 0)
 
     def _admit(self) -> None:
         """Fill free slots from the scheduler.  Admission is a prefix-cache
@@ -843,7 +860,7 @@ class DecodeServer:
                     full = next((e for e in candidates
                                  if e.length == plen and e.logits is not None), None)
                     if full is not None:
-                        self._splice(req.uid, b, full.caches, plen)
+                        self._splice(req.uid, b, full.caches)
                         spec = self._fire("prefix.splice")
                         if spec is not None:
                             # corrupted checkpoint splice: caught downstream by
@@ -893,7 +910,7 @@ class DecodeServer:
                     logits, pcaches = self._prefill(self.params, toks)
                 self._m_prompt_steps.inc(plen)
                 self._tick_prompt_steps += plen
-                self._splice(req.uid, b, pcaches, plen)
+                self._splice(req.uid, b, pcaches)
                 if pc is not None:
                     pc.insert(req.prompt, pcaches, logits=logits[0],
                               resumable=False)
@@ -941,7 +958,7 @@ class DecodeServer:
             self._cache_boundary(job)
             if job.pos >= plen:
                 self._jobs.remove(job)
-                self._splice(job.req.uid, job.slot, job.caches, plen)
+                self._splice(job.req.uid, job.slot, job.caches)
                 self.reserved[job.slot] = False
                 self._start_request(job.req, job.slot, job.logits[0])
             else:
@@ -1102,7 +1119,8 @@ class DecodeServer:
         :meth:`_make_block_fn` behind one packed int32 array each way
         (:func:`pack_block_inputs`, :func:`pack_block_outputs`), so a block
         costs one upload and one fetch.  Named ``block`` like the scan it
-        wraps: profilers show the decode program as ``jit_block``."""
+        wraps: profilers show the decode program as ``jit_block``.  The
+        caches are donated: the block updates them in place."""
         scan = self._make_block_fn(k)
 
         def block(params, caches, packed, key):
@@ -1112,7 +1130,7 @@ class DecodeServer:
             caches, cur, pos, live, _, key = carry
             return caches, key, pack_block_outputs(*outs, cur, pos, live)
 
-        return jax.jit(block)
+        return jax.jit(block, donate_argnums=1)
 
     def step_block(self) -> int:
         """K decode ticks in ONE device dispatch; returns #live after.
@@ -1143,7 +1161,7 @@ class DecodeServer:
             k = self.block_k
             fn = self._block_fns.get(k)
             if fn is None:
-                fn = self._block_fns[k] = self._make_packed_block_fn(k)
+                fn = self._block_fns[k] = self._on_mesh(self._make_packed_block_fn(k))
             temps = np.array(
                 [r.temperature if r is not None else 0.0 for r in self.slot_req],
                 np.float32)

@@ -30,7 +30,8 @@ def _batch(cfg, key, B=2, S=16):
 @pytest.mark.parametrize("arch", ARCH_IDS)
 def test_full_config_is_well_formed(arch):
     cfg = get_config(arch)
-    assert cfg.n_groups * len(cfg.layer_pattern) + len(cfg.tail_pattern) == cfg.n_layers
+    assert (cfg.first_dense_layers + cfg.n_groups * len(cfg.layer_pattern)
+            + len(cfg.tail_pattern)) == cfg.n_layers
     assert len(applicable_shapes(cfg)) >= 2
 
 

@@ -4,6 +4,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels.expert_gmm.ops import expert_gmm
+from repro.kernels.expert_gmm.ref import expert_gmm_ref
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flash_attention.ref import flash_attention_ref
 from repro.kernels.int8_matmul.ops import int8_matmul, quantized_matmul
@@ -201,3 +203,25 @@ def test_vmem_limit_counts_tiled_blocks():
     assert w == 32 << 20
     assert vmem_limit(0) == DEFAULT_SCOPED_VMEM
     assert vmem_limit(w) > w
+
+
+# ---------------------------------------------------------------------------
+# expert_gmm (held-expert grouped product)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("sizes", [(40, 0, 30, 58), (128, 0, 0, 0), (0, 0, 0, 256),
+                                   (100, 100, 100, 84)],
+                         ids=["ragged", "one-group", "none-held", "straddling"])
+@pytest.mark.parametrize("depth", [1, 3], ids=["layer", "stack"])
+def test_expert_gmm_matches_ref(sizes, depth):
+    """Rows by held expert (the last group holds the rows of experts held
+    elsewhere, which come out zero); groups may be empty or straddle a
+    128-row tile; the weight stack is read at its last layer."""
+    E, K, N = len(sizes) - 1, 64, 48
+    lhs = jnp.asarray(RNG.normal(size=(sum(sizes), K)), jnp.float32)
+    rhs = jnp.asarray(RNG.normal(size=(depth, E, K, N)), jnp.float32)
+    gs = jnp.asarray(sizes, jnp.int32)
+    layer = jnp.int32(depth - 1)
+    got = np.asarray(expert_gmm(lhs, rhs, gs, layer))
+    np.testing.assert_allclose(got, expert_gmm_ref(lhs, rhs, gs, layer), atol=1e-4, rtol=1e-5)
+    np.testing.assert_array_equal(got[sum(sizes[:-1]):], 0.0)

@@ -259,7 +259,7 @@ def test_lstm_decode_server_end_to_end(key):
 
     prompt = [2, 2, 3]
     lg, pc = lm.prefill(params, cfg, jnp.asarray([prompt]))
-    c = splice_cache(lm.init_cache(cfg, 1, 32), pc, 0, 3)
+    c = splice_cache(lm.init_cache(cfg, 1, 32), pc, 0)
     cur = int(jnp.argmax(lg[0]))
     outs = [cur]
     for t in range(3):

@@ -377,16 +377,16 @@ def test_splice_cache_overlength_full_attention_raises(smollm):
     _, pc = lm.prefill(params, cfg, toks)
     dst = lm.init_cache(cfg, 2, S)
     with pytest.raises(ValueError, match="reject or truncate"):
-        splice_cache(dst, pc, 0, 12, S)
+        splice_cache(dst, pc, 0, S)
     with pytest.raises(ValueError, match="reject or truncate"):
-        splice_cache(dst, pc, 0, 12)          # no max_seq: conservative
+        splice_cache(dst, pc, 0)              # no max_seq: conservative
     # sliding-window arch: the same over-length splice wraps (ring semantics)
     gcfg = get_smoke_config("gemma3-27b")
     gparams = lm.init_params(gcfg, jax.random.PRNGKey(0))
     gtoks = jax.random.randint(jax.random.PRNGKey(0), (1, 24), 1, gcfg.vocab)
     _, gpc = lm.prefill(gparams, gcfg, gtoks)
     gdst = lm.init_cache(gcfg, 2, 32)         # window=16 < 32: rings may wrap
-    out = splice_cache(gdst, gpc, 0, 24, 32)
+    out = splice_cache(gdst, gpc, 0, 32)
     assert jax.tree_util.tree_structure(out) == jax.tree_util.tree_structure(gdst)
 
 

@@ -41,6 +41,14 @@ def test_sharded_serving_subprocess():
 
 
 @pytest.mark.slow
+def test_sharded_moe_serving_subprocess():
+    """(data=2, model=4): the held experts divide over ``model`` and run as a
+    shard_map; greedy tokens equal the unsharded server's, both drivers,
+    with all experts held and with half of them."""
+    _run("run_sharded_moe_serving.py", ["MOE_EP_OK", "MOE_EP_CUT_OK"])
+
+
+@pytest.mark.slow
 def test_sharded_synthesis_subprocess():
     """Mesh-aware synthesize()/backends: TP all-reduce at the gate
     boundary, pallas shard_map over the data axis, mesh-keyed memo."""

@@ -90,7 +90,7 @@ def test_server_matches_oracle(arch, key):
     # oracle for request 2
     prompt = [3, 2, 3]
     lg, pc = lm.prefill(params, cfg, jnp.asarray([prompt]))
-    c = splice_cache(lm.init_cache(cfg, 1, 48), pc, 0, 3)
+    c = splice_cache(lm.init_cache(cfg, 1, 48), pc, 0)
     cur = int(jnp.argmax(lg[0]))
     outs = [cur]
     for t in range(3):

@@ -7,15 +7,21 @@ state-update `f`, and the logits head is the output map `g`.
 
 Pure jnp by default (dry-run/CPU safe); ``use_pallas=True`` routes the
 prefill attention core to the Pallas flash kernel (validated in interpret
-mode in tests).
+mode in tests).  MLA's decode tick always runs its Pallas kernel,
+``mla_decode`` (Mosaic on a TPU, the interpreter elsewhere).
 """
 
 from __future__ import annotations
 
+import functools
+import math
 from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from repro.kernels.mla_decode import ops as mla_ops
 
 from .config import ModelConfig
 from .layers import apply_rope, dense_init, rmsnorm, rmsnorm_params, yarn_freqs, yarn_get_mscale
@@ -261,6 +267,7 @@ def mla_decode(p, cfg: ModelConfig, x, cache: PyTree, pos, layer=None):
     W_UK is absorbed into the query, W_UV into the output:
         score = (q_nope Wuk_h) · c_kv + q_rope · k_rope
         out_h = (probs · c_kv) Wuv_h
+    The latent products run in :func:`_latent_attention`.
     """
     B, S, _ = x.shape
     H = cfg.n_heads
@@ -276,22 +283,42 @@ def mla_decode(p, cfg: ModelConfig, x, cache: PyTree, pos, layer=None):
     new_cache = {"c_kv": cache["c_kv"].at[at].set(c_new.astype(cache["c_kv"].dtype)),
                  "k_rope": cache["k_rope"].at[at].set(kr_new.astype(cache["k_rope"].dtype))}
     c_kv, k_rope = new_cache["c_kv"], new_cache["k_rope"]
-    if layer is not None:
-        c_kv, k_rope = c_kv[layer], k_rope[layer]
+    if layer is None:
+        c_kv, k_rope, layer = c_kv[None], k_rope[None], 0
 
     w_uk = p["w_uk"].reshape(r, H, dn)
     q_lat = jnp.einsum("bshd,rhd->bshr", q_nope.astype(jnp.float32), w_uk.astype(jnp.float32))
-    s_lat = jnp.einsum("bshr,btr->bhst", q_lat, c_kv.astype(jnp.float32))
-    s_rope = jnp.einsum("bshd,btd->bhst", q_rope.astype(jnp.float32), k_rope.astype(jnp.float32))
-    scores = (s_lat + s_rope) * mla_softmax_scale(cfg)
-    T = c_kv.shape[1]
-    mask = jnp.arange(T)[None, None, None, :] <= qpos[:, None, :, None]
-    scores = jnp.where(mask, scores, NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1)
-    o_lat = jnp.einsum("bhst,btr->bshr", probs, c_kv.astype(jnp.float32))
+    o_lat = _latent_attention(q_lat, q_rope.astype(jnp.float32), c_kv, k_rope,
+                              jnp.asarray(layer, jnp.int32), qpos, mla_softmax_scale(cfg))
     w_uv = p["w_uv"].reshape(r, H, dv)
     out = jnp.einsum("bshr,rhd->bshd", o_lat, w_uv.astype(jnp.float32)).astype(x.dtype)
     return out.reshape(B, S, -1) @ p["wo"], new_cache
+
+
+def _latent_attention(q_lat, q_rope, c_kv, k_rope, layer, qpos, scale):
+    """[B, S, H, r] ``o_lat`` of queries at ``qpos`` against the stacks at
+    ``layer``.  A decode tick (S == 1) runs the ``mla_decode`` kernel,
+    which reads each slot's rows up to its position once; a chunk of
+    several tokens (resumable prefill) runs the jnp products over the whole
+    cache, which a one-token kernel does not tile for.
+
+    Under a device mesh the kernel runs as a ``shard_map``, since GSPMD
+    cannot partition a Mosaic call: slots over the data axes, as the
+    sharding rules lay out the latent stacks, where they divide the batch,
+    else replicated."""
+    if q_lat.shape[1] > 1:
+        return mla_ops.mla_decode_ref(q_lat, q_rope, c_kv, k_rope, layer, qpos, scale=scale)
+    kernel = functools.partial(mla_ops.mla_decode, scale=scale)
+    args = (q_lat[:, 0], q_rope[:, 0], c_kv, k_rope, layer, qpos[:, 0])
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.size > 1:
+        dp = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
+        n_dp = math.prod(mesh.shape[a] for a in dp)
+        sl = dp if dp and q_lat.shape[0] % n_dp == 0 else None
+        kernel = jax.shard_map(
+            kernel, in_specs=(P(sl), P(sl), P(None, sl), P(None, sl), P(), P(sl)),
+            out_specs=P(sl), check_vma=False)
+    return kernel(*args)[:, None]
 
 
 # ---------------------------------------------------------------------------

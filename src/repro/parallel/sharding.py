@@ -212,8 +212,9 @@ def cache_specs(cfg: ModelConfig, cache_tree: PyTree, mesh: Mesh, *, shard_seq: 
         p = _path_str(path)
         shp = leaf.shape
         spec = [None] * len(shp)
-        # leaves under "groups/" are stacked [G, B, ...]; "tail/" are [B, ...]
-        off = 1 if p.startswith("groups") else 0
+        # leaves under "groups/" and "lead/" are stacked [G, B, ...];
+        # "tail/" are [B, ...]
+        off = 1 if p.startswith(("groups", "lead")) else 0
 
         def put(i, axis):
             if 0 <= off + i < len(spec):

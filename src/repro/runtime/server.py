@@ -51,6 +51,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs as obs_lib
+from repro.kernels.mla_decode import ops as mla_ops
 from repro.models import lm
 from repro.models.config import ModelConfig
 
@@ -133,6 +134,14 @@ def fill_slot(caches: PyTree, b, value, *, num_slots: int,
         return leaf
 
     return jax.tree_util.tree_map(one, caches)
+
+
+def mla_layers(caches: PyTree) -> int:
+    """MLA layers a decode step runs over ``caches``: the depth of each
+    latent stack ([G, B, S, r]), one for an unstacked [B, S, r] latent."""
+    return sum(leaf.shape[0] if leaf.ndim == 4 else 1
+               for path, leaf in jax.tree_util.tree_flatten_with_path(caches)[0]
+               if getattr(path[-1], "key", None) == "c_kv")
 
 
 # A decode block crosses the host↔device boundary as one int32 array each
@@ -353,6 +362,13 @@ class DecodeServer:
         self._m_h2d = m.counter("block_transfers",
                                 "decode-block boundary transfers", dir="h2d")
         self._m_tokens = m.counter("decoded_tokens", "tokens generated")
+        # MLA latent cache rows the decode kernel reads (each slot's tiles up
+        # to its position) against the rows the cache reserves; counted on
+        # the host from the positions, for MLA models only
+        self._mla_layers = mla_layers(self.caches)
+        lat_help = "MLA latent cache rows: read by decode, or reserved"
+        self._m_lat_read = m.counter("latent_rows", lat_help, kind="read")
+        self._m_lat_reserved = m.counter("latent_rows", lat_help, kind="reserved")
         # prefill-phase telemetry: per-tick boundedness + cache savings
         self._m_prompt_steps = m.counter("prompt_steps_computed",
                                          "prompt tokens run on device")
@@ -1021,6 +1037,8 @@ class DecodeServer:
             with self._tr.span("device_sync", cat="sync"):
                 logits = np.asarray(logits)
         self._m_syncs.inc()
+        if self._mla_layers:
+            self._count_latent_rows(self.pos[None])
         self.pos += self.live.astype(np.int32)
         now = time.perf_counter()
         spec = self._fire("decode.nan_logits")
@@ -1132,6 +1150,13 @@ class DecodeServer:
 
         return jax.jit(block, donate_argnums=1)
 
+    def _count_latent_rows(self, ticks: np.ndarray) -> None:
+        """``ticks`` [K, B]: the query position of every slot in each of K
+        decode ticks."""
+        tiles = int(mla_ops.slot_tiles(ticks, self.S).sum())
+        self._m_lat_read.inc(tiles * mla_ops.tile_rows(self.S) * self._mla_layers)
+        self._m_lat_reserved.inc(ticks.size * self.S * self._mla_layers)
+
     def step_block(self) -> int:
         """K decode ticks in ONE device dispatch; returns #live after.
 
@@ -1170,6 +1195,7 @@ class DecodeServer:
                  for r in self.slot_req], np.int32)
             packed_in = pack_block_inputs(self.cur_tokens, self.pos, self.live,
                                           remaining, temps)
+            pos0 = self.pos
         with self._tr.span("decode_block", cat="decode",
                            args={"live": live_n, "k": k}):
             try:
@@ -1192,6 +1218,9 @@ class DecodeServer:
         with self._tr.span("post_block", cat="decode",
                            args={"emitted": int(emitted.sum())}):
             self._m_syncs.inc()
+            if self._mla_layers:
+                # a slot's query position moves on after each tick it is live
+                self._count_latent_rows(pos0 + np.cumsum(emitted, axis=0) - emitted)
             now = time.perf_counter()
             # quarantine pass: a slot that went non-finite at inner tick t
             # produced garbage from t on — drop those emissions (and any bogus
@@ -1275,6 +1304,10 @@ class DecodeServer:
             "scheduler": self.scheduler.telemetry(),
             "health": self.health(),
         }
+        if self._mla_layers:
+            read, reserved = self._m_lat_read.value, self._m_lat_reserved.value
+            out["latent_rows"] = {"read": int(read), "reserved": int(reserved),
+                                  "share": read / max(reserved, 1)}
         if self.plan is not None:
             out["mesh"] = dict(
                 self.plan.describe(),

@@ -9,6 +9,10 @@ MOE_EP_OK     — the held experts' weights stay divided over ``model`` (each
                 device's part summed over ``model``.
 MOE_EP_CUT_OK — a server that holds 4 of the 8 experts, divided 1 a device,
                 gives the tokens of the unsharded server holding the same 4.
+
+In both, every MLA latent stack (the leading dense layer's too) stays
+divided over ``data`` by slot, and the decode program runs the
+``mla_decode`` kernel inside a shard_map and gathers no latent stack whole.
 """
 import dataclasses
 import os
@@ -47,6 +51,19 @@ def drain(cfg, params, plan=None, **kw):
     return {r.uid: list(r.out_tokens) for r in done}, srv
 
 
+def pallas_names_in_shard_map(jaxpr, inside=False):
+    """Names of the Pallas calls in ``jaxpr``, and whether each sits inside
+    a shard_map."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append((eqn.params["name"], inside))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += pallas_names_in_shard_map(
+                sub, inside or eqn.primitive.name == "shard_map")
+    return found
+
+
 def check(cfg, tag):
     params = lm.init_params(cfg, jax.random.PRNGKey(0))
     base, _ = drain(cfg, params)
@@ -62,6 +79,17 @@ def check(cfg, tag):
     whole = "f32[%s]" % ",".join(map(str, w_in.shape))
     gathered = re.findall(r"= (\S+) all-gather\(", hlo)
     assert not any(g.startswith(whole) for g in gathered), gathered
+    for stack in (srv.caches["lead"]["b0_attn"], srv.caches["groups"]["b0_moe"]):
+        for leaf in stack.values():
+            assert {s.data.shape[1] for s in leaf.addressable_shards} == {4 // plan.dp}
+            latent = "f32[%s]" % ",".join(map(str, leaf.shape))
+            assert not any(g.startswith(latent) for g in gathered), gathered
+    with jax.set_mesh(plan.mesh):
+        jaxpr = jax.make_jaxpr(lambda p, t, c, pos: lm.decode_step(p, cfg, t, c, pos))(
+            srv.params, jnp.zeros((4, 1), jnp.int32), srv.caches,
+            jnp.zeros((4,), jnp.int32))
+    kernels = pallas_names_in_shard_map(jaxpr.jaxpr)
+    assert ("mla_decode", True) in kernels and ("mla_decode", False) not in kernels, kernels
     assert base == shard, f"{tag}: per-token driver diverged:\n{base}\n{shard}"
     shard_p, _ = drain(cfg, params, plan=plan, persistent=True, block_k=3)
     assert base == shard_p, f"{tag}: persistent driver diverged:\n{base}\n{shard_p}"

@@ -13,6 +13,7 @@ arithmetic in another batching, so the bound is 1e-5 of the largest value.
 """
 
 import dataclasses
+import functools
 import importlib.util
 import math
 from pathlib import Path
@@ -23,6 +24,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config, get_smoke_config
+from repro.kernels.mla_decode import ops as mla_ops
 from repro.models import attention as att
 from repro.models import layers, lm
 from repro.models import moe as moe_lib
@@ -240,3 +242,116 @@ def test_server_cache_lives_once(arch):
     srv.run_until_drained()
     for r, p in zip(reqs, prompts):
         assert r.out_tokens == _greedy_alone(cfg, params, p, 7, S), r.uid
+
+
+def _latent_path(mp, path):
+    """Route the decode tick's latent products: the ``mla_decode`` kernel
+    over 8-row tiles (so a smoke cache spans several), or the jnp products
+    over the whole cache."""
+    if path == "kernel":
+        fn = functools.partial(mla_ops.mla_decode, block_rows=8)
+    else:
+        def fn(q_lat, q_rope, c_kv, k_rope, layer, pos, *, scale):
+            return mla_ops.mla_decode_ref(q_lat[:, None], q_rope[:, None], c_kv, k_rope,
+                                          layer, pos[:, None], scale=scale)[:, 0]
+    mp.setattr(att.mla_ops, "mla_decode", fn)
+
+
+def test_mla_decode_kernel_matches_jnp_path(model):
+    """Decode ticks through the kernel give the jnp path's logits and
+    caches: the same arithmetic, another order of summation (a later
+    layer's new row follows the earlier layers' attention)."""
+    cfg, params, toks = model
+    logits0, pc = lm.prefill(params, cfg, toks[:, :P])
+    from repro.runtime.server import splice_cache
+
+    runs = []
+    for path in ("kernel", "jnp"):
+        caches = lm.init_cache(cfg, 2, P + N)
+        for b in range(2):
+            caches = splice_cache(caches, jax.tree.map(lambda a, b=b: a[:, b:b + 1], pc), b)
+        with pytest.MonkeyPatch.context() as mp:
+            _latent_path(mp, path)
+            step = jax.jit(lambda p, t, c, pos: lm.decode_step(p, cfg, t, c, pos))
+            out = []
+            for t in range(P, P + N - 1):      # positions 11-17: 2 and 3 tiles
+                logits, caches = step(params, toks[:, t:t + 1], caches,
+                                      jnp.asarray([t, t - 4], jnp.int32))
+                out.append(np.asarray(logits))
+        runs.append((np.stack(out), jax.tree.map(np.asarray, caches)))
+    _close(runs[0][0], runs[1][0], 1e-5)
+    jax.tree.map(lambda a, b: _close(a, b, 1e-5), runs[0][1], runs[1][1])
+
+
+def test_server_greedy_tokens_kernel_vs_jnp_path():
+    """32 decode ticks of DecodeServer give the same greedy tokens through
+    the kernel as through the jnp path."""
+    from repro.runtime import DecodeServer, Request
+
+    cfg = get_smoke_config("deepseek-v2-lite-16b")
+    params = lm.init_params(cfg, jax.random.PRNGKey(0))
+    rng = np.random.default_rng(9)
+    prompts = [rng.integers(1, cfg.vocab, n).tolist() for n in (3, 12, 7)]
+    served = []
+    for path in ("kernel", "jnp"):
+        with pytest.MonkeyPatch.context() as mp:
+            _latent_path(mp, path)
+            srv = DecodeServer(cfg, params, num_slots=4, max_seq=48, persistent=True,
+                               block_k=4)
+            for i, p in enumerate(prompts):
+                srv.submit(Request(uid=i, prompt=p, max_new_tokens=33))
+            done = srv.run_until_drained()
+        assert srv.decode_syncs * 4 == 32
+        served.append({r.uid: r.out_tokens for r in done})
+    assert served[0] == served[1]
+
+
+def test_chunked_prefill_takes_the_jnp_path(model, monkeypatch):
+    """A chunk of several tokens (resumable prefill) runs the jnp products;
+    only a one-token decode tick runs the kernel."""
+    cfg, params, toks = model
+
+    def kernel(*a, **k):
+        raise AssertionError("the mla_decode kernel ran")
+
+    monkeypatch.setattr(att.mla_ops, "mla_decode", kernel)
+    caches = lm.init_cache(cfg, 2, P + N)
+    _, caches = jax.jit(lambda p, t, c: lm.prefill_chunk(p, cfg, t, c, jnp.int32(0)))(
+        params, toks[:, :5], caches)
+    with pytest.raises(AssertionError, match="kernel ran"):
+        jax.jit(lambda p, t, c: lm.decode_step(p, cfg, t, c, jnp.int32(5)))(
+            params, toks[:, 5:6], caches)
+
+
+def test_latent_rows_hand_count():
+    """``latent_rows``: over one block of 4 ticks, slot 0 (prompt 510) is
+    queried at 510, 511, 512 and, retired, again at 513; slot 1 (prompt 20)
+    at 20-23.  With 512-row tiles and 3 MLA layers that is (1 + 1 + 2 + 2)
+    + 4 tiles read, of 4 ticks x 2 slots x 1100 rows reserved."""
+    from repro.runtime import DecodeServer, Request
+    from repro.runtime.server import mla_layers
+
+    cfg = get_smoke_config("deepseek-v2-lite-16b")
+    params = lm.init_params(cfg, jax.random.PRNGKey(0))
+    S = 1100
+    srv = DecodeServer(cfg, params, num_slots=2, max_seq=S, persistent=True, block_k=4)
+    assert mla_layers(srv.caches) == 3 and mla_ops.tile_rows(S) == 512
+    srv.submit(Request(uid=0, prompt=[1] * 510, max_new_tokens=4))
+    srv.submit(Request(uid=1, prompt=[2] * 20, max_new_tokens=6))
+    srv.step_block()
+    rows = srv.stats()["latent_rows"]
+    assert rows["read"] == (6 + 4) * 512 * 3
+    assert rows["reserved"] == 4 * 2 * S * 3
+    assert rows["share"] == pytest.approx(rows["read"] / rows["reserved"])
+    # the per-token driver counts its one tick: slot 0 at 513, slot 1 at 24
+    srv.step()
+    assert srv.stats()["latent_rows"]["read"] == (6 + 4 + 2 + 1) * 512 * 3
+
+
+def test_latent_rows_only_for_mla_models():
+    from repro.runtime import DecodeServer
+
+    cfg = get_smoke_config("falcon-mamba-7b")
+    srv = DecodeServer(cfg, lm.init_params(cfg, jax.random.PRNGKey(0)), num_slots=2,
+                       max_seq=16)
+    assert "latent_rows" not in srv.stats()

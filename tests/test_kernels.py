@@ -10,6 +10,8 @@ from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flash_attention.ref import flash_attention_ref
 from repro.kernels.int8_matmul.ops import int8_matmul, quantized_matmul
 from repro.kernels.int8_matmul.ref import int8_matmul_ref, quantize_matmul_ref
+from repro.kernels.mla_decode.ops import mla_decode
+from repro.kernels.mla_decode.ref import mla_decode_ref
 from repro.kernels.ssm_scan.ops import ssm_scan
 from repro.kernels.ssm_scan.ref import ssm_scan_ref
 from repro.kernels.tanh_lut.ops import make_lut, tanh_lut
@@ -225,3 +227,30 @@ def test_expert_gmm_matches_ref(sizes, depth):
     got = np.asarray(expert_gmm(lhs, rhs, gs, layer))
     np.testing.assert_allclose(got, expert_gmm_ref(lhs, rhs, gs, layer), atol=1e-4, rtol=1e-5)
     np.testing.assert_array_equal(got[sum(sizes[:-1]):], 0.0)
+
+
+# ---------------------------------------------------------------------------
+# mla_decode (MLA latent attention, one query token a slot)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("H,r,dr", [(4, 32, 8), (16, 512, 64)], ids=["tiny", "published"])
+@pytest.mark.parametrize("S,tk", [(64, 16), (40, 16)], ids=["whole-tiles", "clamped-last-tile"])
+@pytest.mark.parametrize("layer", ["first", "last"])
+def test_mla_decode_matches_ref(H, r, dr, S, tk, layer):
+    """One batch holds slots at positions 0, tk - 1, tk, S_max - 1 and two
+    random ones, so a slot reads one tile, exactly one, two, every tile,
+    and between; a cache that tk does not divide reads its last tile from
+    S_max - tk.  The stack is read at its first or last layer."""
+    G = 3
+    pos = jnp.asarray([0, tk - 1, tk, S - 1, *RNG.integers(0, S, 2)], jnp.int32)
+    B = pos.shape[0]
+    q_lat = jnp.asarray(RNG.normal(size=(B, H, r)), jnp.float32)
+    q_rope = jnp.asarray(RNG.normal(size=(B, H, dr)), jnp.float32)
+    c_kv = jnp.asarray(RNG.normal(size=(G, B, S, r)), jnp.float32)
+    k_rope = jnp.asarray(RNG.normal(size=(G, B, S, dr)), jnp.float32)
+    g = jnp.int32(0 if layer == "first" else G - 1)
+    scale = (dr + 16) ** -0.5
+    got = mla_decode(q_lat, q_rope, c_kv, k_rope, g, pos, scale=scale, block_rows=tk)
+    want = mla_decode_ref(q_lat[:, None], q_rope[:, None], c_kv, k_rope, g, pos[:, None],
+                          scale=scale)[:, 0]
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5, rtol=1e-5)

@@ -160,3 +160,55 @@ def test_held_experts_compile_over_four_chips(topo, monkeypatch):
     assert "%expert_gmm" in hlo
     gathered = re.findall(r"= (\S+) all-gather\(", hlo)
     assert not any(g.startswith("f32[5,8,") for g in gathered), gathered
+
+
+@pytest.mark.parametrize("depth", [1, 5], ids=["layer", "stack"])
+def test_mla_decode_compiles_at_deepseek_widths(one_chip, depth):
+    """MLA's latent decode kernel at DeepSeek-V2-Lite's widths (16 heads,
+    r = 512, dr = 64) over 64 slots x 8192 positions of a latent stack read
+    at a layer index.  The k_rope stack enters as the TPU lays it out, so
+    no operand is copied: the program needs no temporary buffer.  The
+    Mosaic call keeps its name, ``mla_decode``."""
+    from repro.kernels.mla_decode import kernel
+
+    def attend(q_lat, q_rope, c_kv, k_rope, layer, pos):
+        return kernel.mla_decode(q_lat, q_rope, c_kv, k_rope, layer, pos, scale=0.1,
+                                 interpret=False)
+
+    compiled = jax.jit(attend).lower(
+        _spec(one_chip, (64, 16, 512)), _spec(one_chip, (64, 16, 64)),
+        _spec(one_chip, (depth, 64, 8192, 512)), _spec(one_chip, (depth, 64, 8192, 64)),
+        _spec(one_chip, (), jnp.int32), _spec(one_chip, (64,), jnp.int32)).compile()
+    _assert_mosaic(compiled)
+    assert "%mla_decode" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)
+
+
+def test_mla_decode_step_keeps_the_latent_stacks_in_place(one_chip, monkeypatch):
+    """A DeepSeek-V2-Lite decode step at published widths (the dense layer
+    and a stack of two MoE layers) over 64 slots x 8192 positions, its
+    caches donated:
+    each layer's new row is written into its stack in place and the kernel
+    reads the stack there, so the step holds no copy of a stack (1.07 GB a
+    layer) beside the aliased caches."""
+    import dataclasses
+
+    from repro.configs.deepseek_v2_lite_16b import CONFIG
+    from repro.kernels.expert_gmm import ops as gmm_ops
+    from repro.kernels.mla_decode import ops as mla_ops
+    from repro.models import lm
+
+    for ops in (gmm_ops, mla_ops):
+        monkeypatch.setattr(ops, "resolve_interpret", lambda _: False)
+    cfg = dataclasses.replace(CONFIG, n_layers=3, n_experts_held=8)
+    on_chip = lambda t: jax.tree.map(lambda s: _spec(one_chip, s.shape, s.dtype), t)
+    params = on_chip(jax.eval_shape(lambda: lm.init_params(cfg, jax.random.PRNGKey(0))))
+    caches = on_chip(jax.eval_shape(lambda: lm.init_cache(cfg, 64, 8192)))
+    step = jax.jit(lambda p, t, c, pos: lm.decode_step(p, cfg, t, c, pos), donate_argnums=2)
+    compiled = step.lower(params, _spec(one_chip, (64, 1), jnp.int32), caches,
+                          _spec(one_chip, (64,), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    cache_bytes = sum(c.size * 4 for c in jax.tree.leaves(caches))
+    assert compiled.as_text().count("custom_call_target=\"tpu_custom_call\"") >= 2
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.temp_size_in_bytes < 64 * 8192 * 512 * 4, mem
